@@ -61,5 +61,8 @@ def bench(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for k, row in run().items():
         print(f"k={k:5d}  " + "  ".join(f"{m}={v:.4f}" for m, v in row.items()))

@@ -830,6 +830,9 @@ def bench_quant(quick: bool = True, out_json: str | None = None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     round_only = "--round-only" in sys.argv
     engine_only = "--engine-only" in sys.argv

@@ -68,4 +68,7 @@ def bench(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(rounds=int(sys.argv[1]) if len(sys.argv) > 1 else 8)

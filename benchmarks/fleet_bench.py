@@ -248,6 +248,9 @@ def bench_fleet(quick: bool = True, out_json: str | None = None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     suffix = "quick.json" if quick else "json"
     out = os.path.join(_REPO_ROOT, f"BENCH_fleet.{suffix}")

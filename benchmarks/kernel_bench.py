@@ -75,5 +75,8 @@ def bench(quick: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for name, us, derived in bench(quick=False):
         print(f"{name},{us:.0f},{derived}")

@@ -53,6 +53,7 @@ __all__ = [
     "aggregate_wire",
     "scatter_wire_sums",
     "scatter_wire_sums_dequant",
+    "dequant_channels",
     "max_intermediate_elems",
 ]
 
@@ -179,15 +180,28 @@ def scatter_wire_sums_dequant(
     contract of the sparse aggregation path is preserved (trace-asserted by
     the bench and tests/test_engine.py).
     """
-    m = mask.astype(jnp.float32)
-    v = q_values.astype(jnp.float32) * scale[..., None] * m
-    if mode == "adaptive":
-        a, b = jnp.abs(v) * v, jnp.abs(v)
-    elif mode in ("zeropad", "mean_nonzero"):
-        a, b = v, m
-    else:
-        raise ValueError(f"unknown aggregation mode: {mode!r}")
+    a, b = dequant_channels(q_values, scale, mask, mode)
     return scatter_wire_sums(a, b, indices, vocab)
+
+
+def dequant_channels(
+    q_values: jax.Array,
+    scale: jax.Array,
+    mask: jax.Array,
+    mode: AggregationMode = "adaptive",
+) -> tuple[jax.Array, jax.Array]:
+    """The two per-entry contribution channels of an int8 wire for the
+    aggregation ``mode`` (adaptive: ``|v|·v`` and ``|v|``; zeropad /
+    mean_nonzero: ``v`` and the transmit mask), with ``v = q · scale``
+    per row and exactly 0 where masked — the input of both the jnp and the
+    Pallas wire scatter."""
+    m = mask.astype(jnp.float32)
+    v = q_values.astype(jnp.float32) * scale[..., None].astype(jnp.float32) * m
+    if mode == "adaptive":
+        return jnp.abs(v) * v, jnp.abs(v)
+    if mode in ("zeropad", "mean_nonzero"):
+        return v, m
+    raise ValueError(f"unknown aggregation mode: {mode!r}")
 
 
 def aggregate_wire(

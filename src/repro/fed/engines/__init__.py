@@ -14,7 +14,7 @@ cohort.  Interchangeable engines execute that phase:
   and the client axis is the handle accelerator backends parallelise over.
 * :class:`FusedEngine` — collapses the batched engine's per-phase calls
   into ONE donated, jitted round body; the client axis can optionally be
-  placed over devices with ``jax.experimental.shard_map``
+  placed over devices with ``jax.shard_map``
   (``shard_clients=True``).
 * :class:`FusedE2EEngine` — the whole round (client AND server phase) as
   one compiled call, sparse wire across the boundary, plus the

@@ -98,19 +98,18 @@ class FusedEngine(BatchedEngine):
         self._fused_cold = fused(0)  # round 0: no broadcast knowledge yet
 
     def _shard_over_clients(self, fn):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.sharding import COHORT_AXIS, cohort_mesh
 
         c, r = P(COHORT_AXIS), P()
         frozen_spec = r if self._shared else c
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=cohort_mesh(),
             in_specs=(c, frozen_spec, c, r, r, r, c, r, c),
             out_specs=(c, c, c, c),
-            check_rep=False,
+            check_vma=False,
         )
 
     def _pad_cohort(self, sel: Sequence[int], batches: dict):

@@ -878,18 +878,16 @@ def make_fused_e2e_round_fn(
         return lora, opt, wire.values, wire.indices, wire.mask, scale, h
 
     if shard_clients:
-        from jax.experimental.shard_map import shard_map
-
         from repro.sharding import COHORT_AXIS, cohort_mesh
 
         c, r = jax.sharding.PartitionSpec(COHORT_AXIS), jax.sharding.PartitionSpec()
         frozen_spec = r if shared_backbone else c
-        client_phase = shard_map(
+        client_phase = jax.shard_map(
             client_phase,
             mesh=cohort_mesh(),
             in_specs=(c, frozen_spec, c, r, r, r, c, r, c),
             out_specs=(c, c, c, c, c, c, c),
-            check_rep=False,
+            check_vma=False,
         )
 
     def fn(lora, frozen, opt, s_lora, s_frozen, s_opt,

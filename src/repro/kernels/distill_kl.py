@@ -13,7 +13,7 @@ accumulators (flash-attention-style):
 and finishes with  KL = U/Z_t - (m_t + log Z_t) + (m_s + log Z_s).
 
 Grid: (row_blocks, vocab_tiles) — vocab innermost so the scratch
-accumulators (SMEM/VMEM-resident (R_b,) vectors) persist across the
+accumulators (VMEM-resident (R_b, 1) columns) persist across the
 sequential tile sweep; the per-row KL is emitted at the last tile.
 """
 
@@ -48,17 +48,19 @@ def _kl_kernel(t_ref, s_ref, out_ref, mt, zt, u, ms, zs, *, inv_temp: float, n_t
 
     # --- teacher online logsumexp + weighted (t - s) accumulator ---
     mt_old = mt[...]
-    mt_new = jnp.maximum(mt_old, jnp.max(t, axis=-1))
+    mt_new = jnp.maximum(mt_old, jnp.max(t, axis=-1, keepdims=True))
     scale_t = jnp.exp(mt_old - mt_new)
-    w = jnp.exp(t - mt_new[:, None])
-    zt[...] = zt[...] * scale_t + jnp.sum(w, axis=-1)
-    u[...] = u[...] * scale_t + jnp.sum(w * (t - s), axis=-1)
+    w = jnp.exp(t - mt_new)
+    zt[...] = zt[...] * scale_t + jnp.sum(w, axis=-1, keepdims=True)
+    u[...] = u[...] * scale_t + jnp.sum(w * (t - s), axis=-1, keepdims=True)
     mt[...] = mt_new
 
     # --- student online logsumexp ---
     ms_old = ms[...]
-    ms_new = jnp.maximum(ms_old, jnp.max(s, axis=-1))
-    zs[...] = zs[...] * jnp.exp(ms_old - ms_new) + jnp.sum(jnp.exp(s - ms_new[:, None]), axis=-1)
+    ms_new = jnp.maximum(ms_old, jnp.max(s, axis=-1, keepdims=True))
+    zs[...] = zs[...] * jnp.exp(ms_old - ms_new) + jnp.sum(
+        jnp.exp(s - ms_new), axis=-1, keepdims=True
+    )
     ms[...] = ms_new
 
     @pl.when(j == n_tiles - 1)
@@ -100,15 +102,12 @@ def distill_kl_pallas(
             pl.BlockSpec((rb, vb), lambda r, j: (r, j)),
             pl.BlockSpec((rb, vb), lambda r, j: (r, j)),
         ],
-        out_specs=pl.BlockSpec((rb,), lambda r, j: (r,)),
-        out_shape=jax.ShapeDtypeStruct((r_all,), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-            pltpu.VMEM((rb,), jnp.float32),
-        ],
+        # per-row results and accumulators are (rb, 1) columns: Mosaic
+        # refuses a rank-1 block that is neither the whole array nor a
+        # multiple of 128 lanes
+        out_specs=pl.BlockSpec((rb, 1), lambda r, j: (r, 0)),
+        out_shape=jax.ShapeDtypeStruct((r_all, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rb, 1), jnp.float32) for _ in range(5)],
         interpret=interpret,
     )(t, s)
-    return out[:rows]
+    return out[:rows, 0]

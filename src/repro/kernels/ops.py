@@ -107,9 +107,8 @@ def scatter_wire_sums_dequant(
     """Dequantize-fused scatter-accumulate from the int8 quantized wire:
     ``q_values/mask/indices (N, ..., k)`` + per-row ``scale (N, ...)`` ->
     ``(num, den)`` each ``(..., vocab)`` fp32 for the given aggregation
-    mode.  The float values and both contribution channels are rebuilt
-    inside the kernel per grid step — the wire crosses HBM at 1 byte/value
-    and nothing of size O(N·B·V) is ever formed."""
+    mode.  Nothing of size O(N·B·V) is ever formed: the contribution
+    channels are O(N·B·k) elementwise work ahead of the scatter kernel."""
     n, k = q_values.shape[0], q_values.shape[-1]
     lead = q_values.shape[1:-1]
     fold = lambda x: x.reshape((n, -1, k))

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.aggregation import dequant_channels
+
 __all__ = [
     "sparse_agg_pallas",
     "scatter_wire_sums_pallas",
@@ -29,11 +31,6 @@ __all__ = [
 ROWS_BLK = 8
 VOCAB_BLK = 2048
 EPS = 1e-12
-
-# scatter_wire_sums: rows per grid step, sized so the two dense (rb, V)
-# output accumulators stay within ~8 MB of VMEM even at 256k vocabularies.
-SCATTER_ROWS_BLK = 8
-_SCATTER_VMEM_BUDGET = 8 * 1024 * 1024
 
 
 def _agg_kernel(stack_ref, out_ref):
@@ -69,54 +66,66 @@ def sparse_agg_pallas(stack: jax.Array, *, interpret: bool = False) -> jax.Array
 
 
 # ---------------------------------------------------------------------------
-# PR-3: scatter-accumulate straight from the sparse wire format.
+# Scatter-accumulate straight from the sparse wire format.
 #
 # The kernel above still READS a densified (N, rows, V) stack — O(N·rows·V)
 # HBM traffic that exists only because the uplink was scattered back to
-# dense.  The wire-format kernel skips that entirely: each grid step owns
-# one (N, R_b, k) block of (value, index) entries (the actual on-air
-# payload) and the two (R_b, V) output accumulators, and scatters each
-# client's k entries into VMEM-resident accumulators.  HBM traffic drops
-# from O(N·rows·V) reads to O(N·rows·k) reads + O(rows·V) writes — the
-# aggregation working set the paper's Top-k sparsification actually implies.
+# dense.  The wire-format kernel reads the (value, index) entries themselves
+# (the actual on-air payload) and writes the two (rows, V) sums once:
+# O(N·rows·k) reads + O(rows·V) writes.
 #
-# The client loop is a fori_loop (N is the cohort size, ~10); each
-# iteration is one k-wide scatter-add into the (R_b, V) accumulator.  The
-# kernel is mode-agnostic: callers pre-compute the two per-entry
-# contribution channels (adaptive: s·v and s; zeropad/mean_nonzero: v and
-# mask), so ONE kernel serves all three aggregation modes.
+# Mosaic has no lowering for an in-kernel scatter, so the scatter is a
+# one-hot matmul on the MXU.  Each vocab id v splits into (v >> 7, v & 127);
+# for one row and a chunk of E entries,
+#
+#     num[hi, lo] += Σ_e  a_e·[hi_e == hi] · [lo_e == lo]
+#                 =  (A @ Lᵀ)[hi, lo],  A (H, E), L (128, E)
+#
+# so the output row lives as an (H, 128) tile (H = V/128 rounded up to 8),
+# the VPU builds the two one-hot operands in O(E·(H + 128)) instead of the
+# O(E·V) of a flat one-hot, and the MXU does the reduction.  Grid: (row
+# blocks, entry chunks); the entry axis is innermost so the (R_b, H, 128)
+# output tiles stay resident while every client's entries of those rows
+# stream through.  The kernel is mode-agnostic: callers pre-compute the two
+# per-entry contribution channels (adaptive: s·v and s; zeropad/mean_nonzero:
+# v and mask), so ONE kernel serves all three aggregation modes.
 # ---------------------------------------------------------------------------
+
+SCATTER_ENTRY_BLK = 512  # wire entries per grid step (lane-aligned)
+_LANES = 128
 
 
 def _scatter_wire_kernel(a_ref, b_ref, idx_ref, num_ref, den_ref):
-    a = a_ref[...].astype(jnp.float32)  # (N, R_b, k)
-    b = b_ref[...].astype(jnp.float32)
-    idx = idx_ref[...]  # (N, R_b, k) int32, valid in [0, V)
-    n, rb, k = a.shape
-    vocab = num_ref.shape[-1]
-    row = jax.lax.broadcasted_iota(jnp.int32, (rb, k), 0)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        num_ref[...] = jnp.zeros_like(num_ref)
+        den_ref[...] = jnp.zeros_like(den_ref)
 
-    def body(i, carry):
-        num, den = carry
-        num = num.at[row, idx[i]].add(a[i])
-        den = den.at[row, idx[i]].add(b[i])
-        return num, den
+    rb, e = a_ref.shape
+    h = num_ref.shape[1]
+    hi_ids = jax.lax.broadcasted_iota(jnp.int32, (h, e), 0)
+    lo_ids = jax.lax.broadcasted_iota(jnp.int32, (_LANES, e), 0)
+    nt = (((1,), (1,)), ((), ()))  # contract the entry axis of both operands
 
-    num, den = jax.lax.fori_loop(
-        0,
-        n,
-        body,
-        (jnp.zeros((rb, vocab), jnp.float32), jnp.zeros((rb, vocab), jnp.float32)),
-    )
-    num_ref[...] = num
-    den_ref[...] = den
+    def row(i, carry):
+        a = a_ref[pl.ds(i, 1), :].astype(jnp.float32)  # (1, E)
+        b = b_ref[pl.ds(i, 1), :].astype(jnp.float32)
+        idx = idx_ref[pl.ds(i, 1), :]
+        hit_hi = hi_ids == (idx >> 7)  # (H, E)
+        onehot_lo = (lo_ids == (idx & (_LANES - 1))).astype(jnp.float32)
 
+        def tile(w):
+            return jax.lax.dot_general(
+                jnp.where(hit_hi, w, 0.0), onehot_lo, nt,
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
 
-def _scatter_rows_block(vocab: int, rows: int) -> int:
-    """Rows per block so the two fp32 (rb, V) accumulators + outputs fit the
-    VMEM budget."""
-    per_row = 4 * vocab * 4  # 2 accumulators + 2 output tiles, fp32
-    return max(1, min(SCATTER_ROWS_BLK, rows, _SCATTER_VMEM_BUDGET // max(1, per_row)))
+        num_ref[i] = num_ref[i] + tile(a)
+        den_ref[i] = den_ref[i] + tile(b)
+        return carry
+
+    jax.lax.fori_loop(0, rb, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("vocab", "interpret"))
@@ -134,74 +143,30 @@ def scatter_wire_sums_pallas(
     must carry zero contributions (their index may be any valid id)."""
     assert a.ndim == 3 and a.shape == b.shape == indices.shape
     n, rows, k = a.shape
-    rb = _scatter_rows_block(vocab, rows)
-    rpad = (-rows) % rb
-    if rpad:
-        pad3 = ((0, 0), (0, rpad), (0, 0))
-        a = jnp.pad(a, pad3)
-        b = jnp.pad(b, pad3)
-        indices = jnp.pad(indices, pad3)  # zero contributions at index 0
-    r_all = a.shape[1]
-    grid = (r_all // rb,)
+    # every client's entries of one row become one row of the kernel input
+    flat = lambda x: jnp.swapaxes(x, 0, 1).reshape(rows, n * k)
+    ents = n * k
+    eb = ents if ents <= SCATTER_ENTRY_BLK else SCATTER_ENTRY_BLK
+    rb = rows if rows <= ROWS_BLK else ROWS_BLK
+    pad = ((0, (-rows) % rb), (0, (-ents) % eb))  # zero contributions at id 0
+    a, b = jnp.pad(flat(a), pad), jnp.pad(flat(b), pad)
+    indices = jnp.pad(flat(indices.astype(jnp.int32)), pad)
+    r_all, e_all = a.shape
+    h = -(-vocab // _LANES)
+    h += (-h) % 8
 
-    wire_spec = pl.BlockSpec((n, rb, k), lambda r: (0, r, 0))
-    out_spec = pl.BlockSpec((rb, vocab), lambda r: (r, 0))
+    wire_spec = pl.BlockSpec((rb, eb), lambda r, j: (r, j))
+    out_spec = pl.BlockSpec((rb, h, _LANES), lambda r, j: (r, 0, 0))
     num, den = pl.pallas_call(
         _scatter_wire_kernel,
-        grid=grid,
+        grid=(r_all // rb, e_all // eb),
         in_specs=[wire_spec, wire_spec, wire_spec],
         out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((r_all, vocab), jnp.float32),
-            jax.ShapeDtypeStruct((r_all, vocab), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((r_all, h, _LANES), jnp.float32)] * 2,
         interpret=interpret,
     )(a, b, indices)
-    return num[:rows], den[:rows]
-
-
-# ---------------------------------------------------------------------------
-# Dequantize-fused wire scatter: the QuantizedWire's int8 values + per-row
-# float32 scale go straight into the kernel — the float values and both
-# per-entry contribution channels are reconstructed in-register per grid
-# step, so the HBM-side wire stays 1 byte/value (vs 4 for pre-dequantized
-# float contributions) and nothing of size O(N·rows·V) ever exists.  Mode
-# is static: unlike the float kernel above (mode-agnostic, callers
-# pre-compute the channels), the fusion point is exactly that the channels
-# are NOT pre-computed, so the kernel must know which ones to build.
-# ---------------------------------------------------------------------------
-
-
-def _scatter_wire_dequant_kernel(
-    q_ref, scale_ref, mask_ref, idx_ref, num_ref, den_ref, *, mode: str
-):
-    q = q_ref[...].astype(jnp.float32)  # (N, R_b, k) int8 -> f32
-    sc = scale_ref[...].astype(jnp.float32)  # (N, R_b)
-    m = mask_ref[...].astype(jnp.float32)  # (N, R_b, k) int8 in {0, 1}
-    idx = idx_ref[...]  # (N, R_b, k) int32, valid in [0, V)
-    v = q * sc[:, :, None] * m  # dequantized values, 0 where masked
-    if mode == "adaptive":
-        a, b = jnp.abs(v) * v, jnp.abs(v)
-    else:  # zeropad / mean_nonzero: value and transmit-count channels
-        a, b = v, m
-    n, rb, k = a.shape
-    vocab = num_ref.shape[-1]
-    row = jax.lax.broadcasted_iota(jnp.int32, (rb, k), 0)
-
-    def body(i, carry):
-        num, den = carry
-        num = num.at[row, idx[i]].add(a[i])
-        den = den.at[row, idx[i]].add(b[i])
-        return num, den
-
-    num, den = jax.lax.fori_loop(
-        0,
-        n,
-        body,
-        (jnp.zeros((rb, vocab), jnp.float32), jnp.zeros((rb, vocab), jnp.float32)),
-    )
-    num_ref[...] = num
-    den_ref[...] = den
+    unflat = lambda x: x.reshape(r_all, h * _LANES)[:rows, :vocab]
+    return unflat(num), unflat(den)
 
 
 @functools.partial(jax.jit, static_argnames=("vocab", "mode", "interpret"))
@@ -221,35 +186,11 @@ def scatter_wire_sums_dequant_pallas(
     ``mask (N, rows, k) int8 in {0, 1}``, ``indices (N, rows, k) int32`` ->
     ``(num, den)`` each ``(rows, vocab)`` fp32 for the given aggregation
     ``mode`` (static).  Masked entries contribute exactly 0 to both
-    channels regardless of their index."""
+    channels regardless of their index.  The channels
+    (:func:`repro.core.aggregation.dequant_channels`) are O(N·rows·k)
+    elementwise work that XLA fuses into the kernel's input relayout; the
+    scatter itself is :func:`scatter_wire_sums_pallas`."""
     assert q_values.ndim == 3 and q_values.shape == mask.shape == indices.shape
     assert scale.shape == q_values.shape[:2]
-    if mode not in ("adaptive", "zeropad", "mean_nonzero"):
-        raise ValueError(f"unknown aggregation mode: {mode!r}")
-    n, rows, k = q_values.shape
-    rb = _scatter_rows_block(vocab, rows)
-    rpad = (-rows) % rb
-    if rpad:
-        pad3 = ((0, 0), (0, rpad), (0, 0))
-        q_values = jnp.pad(q_values, pad3)
-        mask = jnp.pad(mask, pad3)  # zero mask -> zero contributions at idx 0
-        indices = jnp.pad(indices, pad3)
-        scale = jnp.pad(scale, ((0, 0), (0, rpad)))
-    r_all = q_values.shape[1]
-    grid = (r_all // rb,)
-
-    wire_spec = pl.BlockSpec((n, rb, k), lambda r: (0, r, 0))
-    scale_spec = pl.BlockSpec((n, rb), lambda r: (0, r))
-    out_spec = pl.BlockSpec((rb, vocab), lambda r: (r, 0))
-    num, den = pl.pallas_call(
-        functools.partial(_scatter_wire_dequant_kernel, mode=mode),
-        grid=grid,
-        in_specs=[wire_spec, scale_spec, wire_spec, wire_spec],
-        out_specs=[out_spec, out_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((r_all, vocab), jnp.float32),
-            jax.ShapeDtypeStruct((r_all, vocab), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q_values, scale, mask, indices)
-    return num[:rows], den[:rows]
+    a, b = dequant_channels(q_values, scale, mask, mode)
+    return scatter_wire_sums_pallas(a, b, indices, vocab, interpret=interpret)

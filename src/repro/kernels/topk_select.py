@@ -67,14 +67,14 @@ def _topk_dynamic_kernel(x_ref, k_ref, out_ref):
     compiled program serves every round of adaptive budgets — the fused
     round engine's requirement (a static k would recompile per round)."""
     x = x_ref[...].astype(jnp.float32)  # (R_b, V)
-    k = k_ref[...]  # (R_b,) int32, pre-clamped to [0, V]
-    lo = jnp.min(x, axis=-1)
-    hi = jnp.max(x, axis=-1) + 1.0
+    k = k_ref[...]  # (R_b, 1) int32, pre-clamped to [0, V]
+    lo = jnp.min(x, axis=-1, keepdims=True)
+    hi = jnp.max(x, axis=-1, keepdims=True) + 1.0
 
     def body(_, carry):
         lo, hi = carry
         mid = 0.5 * (lo + hi)
-        cnt = jnp.sum((x >= mid[:, None]).astype(jnp.int32), axis=-1)
+        cnt = jnp.sum((x >= mid).astype(jnp.int32), axis=-1, keepdims=True)
         take = cnt >= k
         return jnp.where(take, mid, lo), jnp.where(take, hi, mid)
 
@@ -82,7 +82,7 @@ def _topk_dynamic_kernel(x_ref, k_ref, out_ref):
     # exactly the dropped-straggler contract; the explicit k > 0 guard below
     # makes it robust to the last-ulp of the bisection regardless.
     lo, hi = jax.lax.fori_loop(0, ITERS, body, (lo, hi))
-    keep = (x >= lo[:, None]) & (k > 0)[:, None]
+    keep = (x >= lo) & (k > 0)
     out_ref[...] = jnp.where(keep, x_ref[...], jnp.zeros_like(x_ref[...]))
 
 
@@ -94,11 +94,13 @@ def topk_mask_dynamic_pallas(
     (threshold semantics; ``ks[i] == 0`` zeroes row i entirely)."""
     assert logits.ndim == 2 and ks.ndim == 1, "fold batch dims before calling"
     rows, vocab = logits.shape
-    ks = jnp.clip(ks.astype(jnp.int32), 0, vocab)
+    # budgets ride as a (rows, 1) column: Mosaic takes a rank-1 block only
+    # when it spans the whole array or a multiple of 128 lanes
+    ks = jnp.clip(ks.astype(jnp.int32), 0, vocab)[:, None]
     rb = rows_block_for(vocab, logits.dtype)
     pad = (-rows) % rb
     x = jnp.pad(logits, ((0, pad), (0, 0))) if pad else logits
-    kp = jnp.pad(ks, (0, pad)) if pad else ks
+    kp = jnp.pad(ks, ((0, pad), (0, 0))) if pad else ks
     grid = (x.shape[0] // rb,)
 
     out = pl.pallas_call(
@@ -106,7 +108,7 @@ def topk_mask_dynamic_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((rb, vocab), lambda r: (r, 0)),
-            pl.BlockSpec((rb,), lambda r: (r,)),
+            pl.BlockSpec((rb, 1), lambda r: (r, 0)),
         ],
         out_specs=pl.BlockSpec((rb, vocab), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, logits.dtype),

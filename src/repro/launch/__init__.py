@@ -1,8 +1,7 @@
 """Launchers: mesh construction, multi-pod dry-run, train/serve/fed drivers.
 
-NOTE: importing ``repro.launch.dryrun`` sets XLA_FLAGS for 512 host devices —
-import it only in a dedicated process (its CLI).  Everything else here is
-import-safe.
+``repro.launch.dryrun`` run as a program pins the CPU backend with 512
+forced host devices; importing it sets nothing.
 """
 
 from repro.launch.mesh import V5E, make_host_mesh, make_production_mesh

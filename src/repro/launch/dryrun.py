@@ -1,16 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
-)
-
-import sys  # noqa: E402
-
-if "--cost-mode" in sys.argv:
-    # python-unroll inner chunk loops BEFORE model modules import, so HLO
-    # cost analysis sees every op (XLA counts while bodies once).
-    os.environ["REPRO_UNROLL"] = "1"
-
 """Multi-pod dry-run: prove every (arch x shape x mesh) lowers + compiles.
 
 For each combination this driver:
@@ -28,22 +15,39 @@ Usage:
   python -m repro.launch.dryrun --all [--multi-pod] [--jobs 4]
 """
 
-import argparse
-import json
-import re
-import subprocess
+import os
 import sys
-import time
 
-import jax
-import numpy as np
+if __name__ == "__main__":
+    # Run as a program, the dry-run compiles against 512 forced HOST
+    # devices and never needs an accelerator: pin the CPU backend so no
+    # process of it (the --all parent included) ever holds a chip.  Set
+    # only here, so importing this module leaks nothing into the importer.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
+    if "--cost-mode" in sys.argv:
+        # python-unroll inner chunk loops BEFORE model modules import, so
+        # HLO cost analysis sees every op (XLA counts while bodies once).
+        os.environ["REPRO_UNROLL"] = "1"
 
-from repro.configs import ARCHITECTURES, INPUT_SHAPES
-from repro.configs.base import ModelConfig
-from repro.launch.mesh import V5E, make_production_mesh
-from repro.launch.steps import make_train_step
-from repro.serve import make_decode_step as make_serve_step, make_prefill_step
-from repro import sharding as sh
+import argparse  # noqa: E402
+import json  # noqa: E402
+import re  # noqa: E402
+import subprocess  # noqa: E402
+import time  # noqa: E402
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.configs import ARCHITECTURES, INPUT_SHAPES  # noqa: E402
+from repro.configs.base import ModelConfig  # noqa: E402
+from repro.launch.mesh import V5E, make_production_mesh  # noqa: E402
+from repro.launch.steps import make_train_step  # noqa: E402
+from repro.serve import make_decode_step as make_serve_step, make_prefill_step  # noqa: E402
+from repro import sharding as sh  # noqa: E402
 
 DEFAULT_OUT = "experiments/dryrun"
 
@@ -328,6 +332,9 @@ def main(argv=None) -> int:
     ap.add_argument("--jobs", type=int, default=4)
     ap.add_argument("--out", default=DEFAULT_OUT)
     args = ap.parse_args(argv)
+    # `python -m` imports the repro.launch package (and jax) before the
+    # environment above is set, so pin the backend through the config too
+    jax.config.update("jax_platforms", "cpu")
 
     if args.all:
         combos = [
@@ -348,6 +355,8 @@ def main(argv=None) -> int:
                     cmd.append("--multi-pod")
                 if args.cost_mode:
                     cmd.append("--cost-mode")
+                # the children compile against forced host devices: they
+                # pin the CPU backend (and the 512-device flag) themselves
                 procs.append(((a, s), subprocess.Popen(cmd)))
             done = [(c, p) for c, p in procs if p.poll() is not None]
             procs = [(c, p) for c, p in procs if p.poll() is None]

@@ -14,6 +14,7 @@ import json
 import math
 import os
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.configs.gpt2_paper import REDUCED_CLIENT, REDUCED_SERVER
 from repro.data import make_banking77_like
@@ -129,6 +130,7 @@ def main(argv=None) -> int:
     ap.add_argument("--public-batch", type=int, default=128)
     ap.add_argument("--out", default="experiments/fed")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.resume and args.ckpt_dir is None:
         ap.error("--resume requires --ckpt-dir")

@@ -24,6 +24,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHITECTURES, get_smoke_config
 from repro.lora import lora_template, map_lora, split_lora
 from repro.serve import (
@@ -72,6 +73,7 @@ def main(argv=None) -> int:
                     help="page tenant adapters from this fed_train --ckpt-dir "
                          "(default: synthetic random adapters)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.from_ckpt is not None and args.arch == "gpt2-paper":
         # fed_train trains REDUCED_CLIENT by default — the smoke config's

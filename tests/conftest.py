@@ -1,23 +1,15 @@
 import os
 import sys
-import tempfile
 
 # tests run against the source tree regardless of install state
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
 jax.config.update("jax_enable_x64", False)
 
 # The suite is compile-bound on CPU; a persistent compilation cache makes
 # warm reruns several times faster (cold runs are unaffected).
-_cache_dir = os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(tempfile.gettempdir(), "jax_compile_cache_repro"),
-)
-try:  # pragma: no cover - best effort, older jax may lack these knobs
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-except Exception:
-    pass
+enable_compile_cache()

@@ -6,9 +6,8 @@ import pytest
 from repro.configs import INPUT_SHAPES, get_config
 from repro.launch.policy import arch_shape_config, input_specs, window_for
 
-# collective parser is defined inside dryrun; re-test its logic via a copy of
-# the regexes on a synthetic HLO snippet without importing the module (which
-# would set XLA_FLAGS in-process).
+# collective parser is defined inside dryrun; test it on a synthetic HLO
+# snippet (importing the module sets no XLA_FLAGS — only its CLI does).
 HLO = """
 ENTRY %main {
   %ag = bf16[16,512]{1,0} all-gather(bf16[1,512]{1,0} %p0), replica_groups={}
@@ -22,8 +21,6 @@ ENTRY %main {
 
 
 def _parser():
-    # load dryrun without executing jax-device side effects? XLA_FLAGS set
-    # is harmless after jax is already initialised in this process.
     from repro.launch import dryrun
 
     return dryrun.collective_bytes

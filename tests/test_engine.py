@@ -543,6 +543,19 @@ _E2E_SHARD_SCRIPT = textwrap.dedent(
             server_distill_steps=2, shard_clients=shard,
         )
 
+    def assert_same_update(new_a, new_b, old):
+        # The sharded and unsharded rounds are different executables, so
+        # their reductions differ in the last bits — and Adam divides each
+        # step by sqrt(v), so where a gradient sits at rounding level its
+        # step can flip by up to 2*lr.  Compare the UPDATE each round made,
+        # leaf by leaf, in norm: within 1% of the update's own size (a wrong
+        # shard or pad row changes it at O(1)); untouched leaves stay equal.
+        for a, b, o in zip(jax.tree.leaves(new_a), jax.tree.leaves(new_b),
+                           jax.tree.leaves(old)):
+            a, b, o = np.asarray(a), np.asarray(b), np.asarray(o)
+            assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(a - o), (
+                np.linalg.norm(a - b), np.linalg.norm(a - o))
+
     sim = ChannelSimulator(4, ChannelConfig(bandwidth_hz=2e5, mean_snr_db=2.0), seed=0)
     pub = jnp.asarray(ds.tokens[:16])
     # n=2 divides the 2 devices exactly; n=3 exercises the masked k=0 padding
@@ -551,6 +564,8 @@ _E2E_SHARD_SCRIPT = textwrap.dedent(
         sel = list(range(n))
         states = sim.states_batched(0, sel)
         plain, shard = e2e(cohort(n), False), e2e(cohort(n), True)
+        c0 = [jax.tree.map(np.asarray, plain.client_params(i)) for i in range(n)]
+        s0 = jax.tree.map(np.asarray, plain._s_lora)
         pp = plain.run_round(sel, pub, None, states, adaptive_k=True, send_h=True)
         ps = shard.run_round(sel, pub, None, states, adaptive_k=True, send_h=True)
         assert pp.ks == ps.ks
@@ -562,12 +577,9 @@ _E2E_SHARD_SCRIPT = textwrap.dedent(
         np.testing.assert_allclose(
             np.asarray(shard._b_logits), np.asarray(plain._b_logits), atol=1e-4)
         for i in range(n):
-            for a, b in zip(jax.tree.leaves(plain.client_params(i)),
-                            jax.tree.leaves(shard.client_params(i))):
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-        for a, b in zip(jax.tree.leaves(plain._s_lora),
-                        jax.tree.leaves(shard._s_lora)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+            assert_same_update(
+                plain.client_params(i), shard.client_params(i), c0[i])
+        assert_same_update(plain._s_lora, shard._s_lora, s0)
         print(f"E2E_SHARD_OK_{n}")
 
     # sharded run_rounds: odd cohorts padded inside the scanned executable,

@@ -2,8 +2,10 @@
 
 The contract under test: ONE donated jitted decode step + ONE shared
 frozen backbone serve a mixed batch of tenants — each request applying its
-own client's LoRA adapter via a slab gather — BIT-IDENTICALLY to running
-every request alone (batch 1) with its adapter merged the classic way;
+own client's LoRA adapter via a slab gather — with the same greedy tokens
+as running every request alone with its adapter merged the classic way,
+and both decodes' logits within float32 rounding of a full-sequence
+reference;
 the AdapterCache pages adapters through LRU slots with exact hit/miss/
 eviction accounting and re-pages evicted adapters to identical outputs;
 and a federation checkpoint (``step_N.fleet/`` shards or monolithic npz)
@@ -13,6 +15,7 @@ is directly servable through ``export_adapters`` with no new format.
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -96,17 +99,40 @@ def _prompts(cfg, batch, seed=3):
 
 
 # ---------------------------------------------------------------------------
-# multi-tenant parity: mixed batch == per-request batch-1, bit for bit
+# multi-tenant parity: mixed batch == per-request single adapter, both
+# against a float32 full-sequence reference
 # ---------------------------------------------------------------------------
 
+# Why a tolerance and not bit equality: the stacked decode (slab gather at
+# batch 8), the single-adapter decode (merged weights, batch 1 for the dense
+# family) and the reference (one full-sequence forward, no decode cache) are
+# three different executables.  XLA orders the float32 reductions of each
+# differently (fusion, batch size, cached vs full attention; measured ~1 ulp
+# apart even with zero adapters), so their logits agree to float32 rounding
+# accumulated over the layers, not bit for bit.  LOGIT_RTOL bounds that at
+# 1e-4 of the logits' own scale — 100x the measured gap, and far below the
+# O(1) change a wrong tenant's adapter makes (test_distinct_tenants_*).
+LOGIT_RTOL = 1e-4
 
-# The dense baseline runs each request truly ALONE (batch 1).  The SSM
-# baseline runs at equal batch: the Mamba2 backbone is not bit-stable
-# across batch SIZES on CPU XLA even with zero adapters in play (fusion
-# orders a reduction differently; measured ~1 ulp on the seed build), so
-# the invariant the adapter machinery can and must guarantee is that the
-# per-request slab gather adds ZERO deviation over the classic
-# merge_lora'd single-adapter decode at the same batch.
+
+def _reference_logits(cfg, params, tokens):
+    """Float32 full-sequence forward at highest matmul precision:
+    ``tokens (L,)`` -> next-token logits at every position ``(L, V)``."""
+    from repro.models import forward
+
+    with jax.default_matmul_precision("highest"):
+        logits, _ = forward(params, cfg, {"tokens": jnp.asarray(tokens)[None]})
+    return np.asarray(logits[0], np.float32)
+
+
+def _assert_logits_close(got, ref):
+    scale = float(np.abs(ref).max())
+    np.testing.assert_allclose(got, ref, rtol=LOGIT_RTOL, atol=LOGIT_RTOL * scale)
+
+
+# The dense baseline runs each request truly ALONE (batch 1); the SSM
+# baseline runs at equal batch (its backbone alone is not bit-stable across
+# batch sizes either, so both sides of the comparison keep the batch).
 @pytest.mark.parametrize("cfg,solo", [(DENSE, True), (SSM, False)], ids=["dense", "ssm"])
 def test_stacked_batch_bit_identical_to_single_adapter(cfg, solo):
     n = 8
@@ -137,8 +163,14 @@ def test_stacked_batch_bit_identical_to_single_adapter(cfg, solo):
             s1 = _session(cfg, base, batch=n)
             t1, l1 = _decode(s1, prompts)
             t1, l1 = t1[b], l1[b]
+        # the reference reads the prompt plus the greedy continuation: its
+        # argmax must reproduce that continuation, and its last position is
+        # the logits both decodes end on
+        ref = _reference_logits(cfg, base, np.concatenate([prompts[b], toks[b]]))
+        np.testing.assert_array_equal(toks[b], ref[PROMPT - 1 : -1].argmax(-1))
         np.testing.assert_array_equal(toks[b], t1)
-        np.testing.assert_array_equal(logits[b], l1)
+        _assert_logits_close(logits[b], ref[-1])
+        _assert_logits_close(l1, ref[-1])
 
 
 def test_distinct_tenants_distinct_outputs():
