@@ -11,7 +11,8 @@ per client — but device memory holds a slab of only ``slots`` adapter rows.
   written back) and the tenant's row is paged in from the
   :class:`AdapterSource` (a live ``FleetStore`` or a ``step_N.fleet``
   shard directory, see :mod:`repro.serve.export`) via ONE jitted donated
-  slab write.
+  slab write, inside a ``serve.page_in`` profiler span tagged with the
+  caller's ``batch``, the ``tenant`` and the ``slot``.
 
 Slots referenced earlier in the same batch are pinned: a lookup never
 evicts an adapter the batch it is resolving still needs.  A batch with
@@ -26,6 +27,7 @@ from typing import Any, Protocol, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.adapters import canonicalize_row, slab_init, slab_set_row
 
@@ -83,7 +85,7 @@ class AdapterCache:
         self.stats = CacheStats()
 
     # -- the serving read path ------------------------------------------
-    def _page_in(self, cid: int, pinned: set[int]) -> int:
+    def _page_in(self, cid: int, pinned: set[int], batch: int) -> int:
         if self._free:
             slot = self._free.pop()
         else:
@@ -94,15 +96,17 @@ class AdapterCache:
                 raise RuntimeError("all slots pinned by the current batch")
             slot = self._slot_of.pop(victim)
             self.stats.evictions += 1
-        row = canonicalize_row(self.source.lora_row(cid), self._like)
-        self.slab = self._write(self.slab, row, np.int32(slot))
+        with TraceAnnotation("serve.page_in", batch=batch, tenant=cid, slot=slot):
+            row = canonicalize_row(self.source.lora_row(cid), self._like)
+            self.slab = self._write(self.slab, row, np.int32(slot))
         self._slot_of[cid] = slot
         return slot
 
-    def lookup(self, ids: Sequence[int]) -> np.ndarray:
+    def lookup(self, ids: Sequence[int], *, batch: int = 0) -> np.ndarray:
         """Slot index per request: ``ids (B,)`` tenant ids -> ``(B,) int32``
         slab slots, paging misses in from the source.  Duplicate ids within
-        a batch share a slot (the first occurrence decides hit vs miss)."""
+        a batch share a slot (the first occurrence decides hit vs miss).
+        ``batch`` tags the page-in spans with the caller's batch."""
         ids = [int(i) for i in ids]
         distinct = len(set(ids))
         if distinct > self.slots:
@@ -122,6 +126,6 @@ class AdapterCache:
                 out[b] = self._slot_of[cid]
             else:
                 self.stats.misses += 1
-                out[b] = self._page_in(cid, pinned)
+                out[b] = self._page_in(cid, pinned, batch)
             pinned.add(cid)
         return out

@@ -11,7 +11,13 @@ and ``launch/dryrun.py`` (kept importable via shims):
     sess.attach([17, 3, 3, 99, ...])      # tenant id per request
     sess.prefill(prompts)                  # (B, L) int32
     tokens = sess.decode(32)               # (B, 32) greedy/sampled
-    sess.stats()                           # cache hits/misses, timing, ...
+    sess.stats()                           # cache hits/misses, compile time
+
+Each layer boundary is a ``jax.profiler.TraceAnnotation`` span, recorded
+only while a profiler session is active: ``serve.attach``, ``serve.reset``,
+``serve.prefill`` and ``serve.step`` here, ``serve.page_in`` in the cache.
+Every span of one batch carries ``batch=<n>``, the count of ``attach``
+calls so far.
 
 Compilation contract: a session compiles at most TWO decode executables —
 the single-adapter step (detached mode) and the stacked multi-tenant step
@@ -30,6 +36,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.lora import split_lora
@@ -79,11 +86,10 @@ class ServeSession:
         self._cache: dict | None = None
         self._logits: jax.Array | None = None
         self._key = jax.random.PRNGKey(cfg.seed)
-        self.tokens_decoded = 0
-        # per-executable first-call (compile) wall time + steady accumulators
+        self._batch = 0  # span tag: the number of attach() calls so far
+        self._pos = 0  # span tag: the decode-cache position of the next step
+        # per-executable first-call wall time (compile + first run)
         self._first_s: dict[str, float] = {}
-        self._steady_s = 0.0
-        self._steady_steps = 0
 
     # -- adapter attach / evict -----------------------------------------
     def attach(self, adapter_ids: Sequence[int], *, reset: bool = True) -> np.ndarray:
@@ -99,10 +105,12 @@ class ServeSession:
             raise ValueError(
                 f"got {len(adapter_ids)} adapter ids for batch {self.cfg.batch}"
             )
-        slots = self.adapters.lookup(adapter_ids)
-        self._slot_idx = jnp.asarray(slots, jnp.int32)
-        if reset:
-            self.reset()
+        self._batch += 1
+        with TraceAnnotation("serve.attach", batch=self._batch):
+            slots = self.adapters.lookup(adapter_ids, batch=self._batch)
+            self._slot_idx = jnp.asarray(slots, jnp.int32)
+            if reset:
+                self.reset()
         return slots
 
     def detach(self) -> None:
@@ -117,45 +125,49 @@ class ServeSession:
     def reset(self, *, frontend: jax.Array | None = None) -> None:
         """Fresh decode cache (and encoder pass for audio families)."""
         mc = self.cfg.model
-        enc_out = None
-        if mc.family == "audio":
-            if frontend is None:
-                frontend = synth_frontend_embeddings(mc, self.cfg.batch)
-            enc_out = _run_encoder(self.params, mc, frontend)
-        self._cache = init_cache(
-            mc, self.cfg.batch, self.cfg.cache_len,
-            window=self.cfg.window, enc_out=enc_out,
-        )
+        with TraceAnnotation("serve.reset", batch=self._batch):
+            enc_out = None
+            if mc.family == "audio":
+                if frontend is None:
+                    frontend = synth_frontend_embeddings(mc, self.cfg.batch)
+                enc_out = _run_encoder(self.params, mc, frontend)
+            self._cache = init_cache(
+                mc, self.cfg.batch, self.cfg.cache_len,
+                window=self.cfg.window, enc_out=enc_out,
+            )
         self._logits = None
+        self._pos = 0
 
     # -- the one decode step --------------------------------------------
     def _timed(self, name: str, fn, *args):
+        """Run a decode executable.  Only its first call waits for the
+        device, to time compile + first run (``first_step_s``); later calls
+        return as soon as they are dispatched."""
+        if name in self._first_s:
+            return fn(*args)
         t0 = time.perf_counter()
         logits, cache = fn(*args)
         logits.block_until_ready()
-        dt = time.perf_counter() - t0
-        if name not in self._first_s:
-            self._first_s[name] = dt  # compile + first run
-        else:
-            self._steady_s += dt
-            self._steady_steps += 1
+        self._first_s[name] = time.perf_counter() - t0
         return logits, cache
 
     def step(self, tokens) -> jax.Array:
         """Feed one token per request, return next-token logits (B, V)."""
-        if self._cache is None:
-            self.reset()
-        tok = jnp.asarray(tokens, jnp.int32)
-        if self._slot_idx is not None:
-            self._logits, self._cache = self._timed(
-                "stacked", self._stacked,
-                self._frozen, self.adapters.slab, self._slot_idx,
-                self._cache, tok,
-            )
-        else:
-            self._logits, self._cache = self._timed(
-                "single", self._single, self.params, self._cache, tok
-            )
+        with TraceAnnotation("serve.step", batch=self._batch, pos=self._pos):
+            if self._cache is None:
+                self.reset()
+            tok = jnp.asarray(tokens, jnp.int32)
+            if self._slot_idx is not None:
+                self._logits, self._cache = self._timed(
+                    "stacked", self._stacked,
+                    self._frozen, self.adapters.slab, self._slot_idx,
+                    self._cache, tok,
+                )
+            else:
+                self._logits, self._cache = self._timed(
+                    "single", self._single, self.params, self._cache, tok
+                )
+            self._pos += 1
         return self._logits
 
     # -- serving loops ---------------------------------------------------
@@ -165,9 +177,10 @@ class ServeSession:
         Smoke-scale prefill — the production full-sequence prefill shapes
         are proven by the dry-run (``make_prefill_step``)."""
         prompts = np.asarray(prompts)
-        self.reset()
-        for t in range(prompts.shape[1]):
-            logits = self.step(prompts[:, t])
+        with TraceAnnotation("serve.prefill", batch=self._batch):
+            self.reset()
+            for t in range(prompts.shape[1]):
+                logits = self.step(prompts[:, t])
         return logits
 
     def decode(self, num_tokens: int, *, temperature: float | None = None):
@@ -186,7 +199,6 @@ class ServeSession:
                 nxt = jnp.argmax(logits, axis=-1)
             out.append(np.asarray(nxt))
             logits = self.step(nxt)
-        self.tokens_decoded += num_tokens * self.cfg.batch
         return np.stack(out, axis=1).astype(np.int32), logits
 
     # -- stats taps ------------------------------------------------------
@@ -200,14 +212,8 @@ class ServeSession:
         return out
 
     def stats(self) -> dict:
-        steady = (
-            self._steady_s / self._steady_steps if self._steady_steps else 0.0
-        )
         s = {
-            "tokens_decoded": self.tokens_decoded,
             "first_step_s": dict(self._first_s),
-            "steady_step_s": steady,
-            "steady_steps": self._steady_steps,
             "executables": self.executables(),
             "attached": self.attached,
         }
