@@ -3,10 +3,15 @@
 Sharding-relevant layout decisions (see DESIGN §4):
   * activations carry explicit head axes: q (B,S,Kv,G,Dh), k/v (B,T,Kv,Dh) —
     the Kv/G axes are what tensor parallelism shards;
-  * the decode KV cache is laid out (B, C, Kv, Dh) with C the cache length;
-    at decode shapes C is sharded along the **sequence** axis over the
-    ``model`` mesh axis (flash-decoding on TPU): every device attends its
-    slice, XLA turns the seq-contraction + softmax into partial
+  * the decode KV cache is laid out (B, Kv, Dh, C) with C, the cache
+    length, minor: the TPU tiles it (Dh, C) without padding, and both
+    decode contractions read it there in place (scores over Dh, output
+    over C).  Decode never writes a layer's cache inside the layer loop:
+    each layer returns its new K/V row, and ``write_kv_rows`` writes every
+    layer's row into the layer-stacked cache with one single-slot update
+    per tensor.  At decode shapes C is sharded along the **sequence** axis
+    over the ``model`` mesh axis (flash-decoding on TPU): every device
+    attends its slice, XLA turns the seq-contraction + softmax into partial
     reductions + ``psum``;
   * sliding-window mode stores a ring buffer of C = window entries with an
     absolute-position side array, so a 524k-token stream needs a 4k cache.
@@ -25,16 +30,26 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.layers import apply_rope, dense_apply, dense_init
 
-__all__ = ["KVCache", "attn_init", "attn_apply", "init_kv_cache", "cross_attn_apply"]
+__all__ = [
+    "KVCache", "KVRow", "attn_init", "attn_apply", "init_kv_cache", "write_kv_rows",
+    "cross_attn_apply",
+]
 
 _NEG_INF = -1e30
 
 
 class KVCache(NamedTuple):
-    k: jax.Array  # (B, C, Kv, Dh) — RoPE already applied
-    v: jax.Array  # (B, C, Kv, Dh)
+    k: jax.Array  # (B, Kv, Dh, C) — RoPE already applied
+    v: jax.Array  # (B, Kv, Dh, C)
     pos: jax.Array  # (C,) absolute position of each slot, -1 = empty
     length: jax.Array  # () int32 — tokens seen so far (absolute)
+
+
+class KVRow(NamedTuple):
+    """One decode step's new K/V entry of one layer."""
+
+    k: jax.Array  # (B, Kv, Dh) — RoPE already applied
+    v: jax.Array  # (B, Kv, Dh)
 
 
 def attn_init(rng: jax.Array, cfg: ModelConfig, *, cross: bool = False) -> dict:
@@ -54,8 +69,8 @@ def init_kv_cache(
     dt = jnp.dtype(dtype or cfg.compute_dtype)
     hd = cfg.head_dim
     return KVCache(
-        k=jnp.zeros((batch, cache_len, cfg.num_kv_heads, hd), dt),
-        v=jnp.zeros((batch, cache_len, cfg.num_kv_heads, hd), dt),
+        k=jnp.zeros((batch, cfg.num_kv_heads, hd, cache_len), dt),
+        v=jnp.zeros((batch, cfg.num_kv_heads, hd, cache_len), dt),
         pos=jnp.full((cache_len,), -1, jnp.int32),
         length=jnp.zeros((), jnp.int32),
     )
@@ -200,14 +215,17 @@ def attn_apply(
     cache: KVCache | None = None,
     lora: dict | None = None,
     causal: bool = True,
-) -> tuple[jax.Array, KVCache | None, jax.Array | None]:
-    """Self-attention.  Returns (output, updated_cache, lora_h).
+) -> tuple[jax.Array, KVRow | None, jax.Array | None]:
+    """Self-attention.  Returns (output, new K/V row or None, lora_h).
 
     Full-sequence mode (cache is None): causal (+optional window) mask over
     the input sequence — used by train and prefill steps.
 
-    Decode mode (cache given): x is (B, 1, D); the new K/V is written into
-    the ring slot ``length % C`` and the query attends over the whole cache.
+    Decode mode (cache: one layer's KVCache): x is (B, 1, D).  The query
+    attends over the cache as it stands, with the new K/V in place of ring
+    slot ``length % C``, whose old entry it is about to overwrite.  The
+    cache is not written here: the new K/V comes back as a ``KVRow``, and
+    ``write_kv_rows`` writes every layer's row at once.
     """
     q_flat, h_q = _project(params, x, "q", cfg, lora)
     k_flat, _ = _project(params, x, "k", cfg, lora)
@@ -241,27 +259,52 @@ def attn_apply(
         y = dense_apply(params["wo"], out.astype(x.dtype), compute_dtype=cfg.compute_dtype)
         return y, None, lora_h
 
-    # ---- decode path: single new token against the cache ----
+    # ---- decode path: one new token against one layer's cache ----
     assert s == 1, "decode mode expects one new token"
-    cache_len = cache.k.shape[1]
-    slot = (cache.length % cache_len).astype(jnp.int32)
-    new_k = jax.lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype), slot, axis=1)
-    new_v = jax.lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype), slot, axis=1)
-    new_pos = jax.lax.dynamic_update_slice_in_dim(
-        cache.pos, cache.length[None].astype(jnp.int32), slot, axis=0
-    )
-    new_cache = KVCache(k=new_k, v=new_v, pos=new_pos, length=cache.length + 1)
-
-    scores = _gqa_scores(q, new_k, cfg)  # (B,H,1,C)
-    valid = new_pos >= 0
-    valid &= new_pos <= cache.length  # all written slots qualify
+    kv, g = cfg.num_kv_heads, cfg.q_per_kv
+    f32 = jnp.float32
+    qg = q.reshape(b, kv, g, hd).astype(f32)
+    k_new = k.reshape(b, kv, hd).astype(cache.k.dtype)
+    v_new = v.reshape(b, kv, hd).astype(cache.v.dtype)
+    scale = hd**-0.5
+    # the cache is read once, where it lies and in its stored layout: the
+    # scores contract Dh, the output contracts C
+    scores = jnp.einsum("bkgd,bkdc->bkgc", qg, cache.k.astype(f32)) * scale
+    score_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new.astype(f32)) * scale
+    slot = cache.length % cache.pos.shape[0]
+    valid = (cache.pos >= 0) & (jnp.arange(cache.pos.shape[0]) != slot)
     if window is not None:
-        valid &= new_pos > cache.length - window
-    scores = jnp.where(valid[None, None, None, :], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = _gqa_output(probs, new_v, cfg).astype(x.dtype)
+        valid &= cache.pos > cache.length - window
+    scores = jnp.where(valid, scores, _NEG_INF)
+    # softmax over the cache's valid slots and the new row
+    top = jnp.maximum(jnp.max(scores, axis=-1), score_new)
+    e = jnp.exp(scores - top[..., None])
+    e_new = jnp.exp(score_new - top)
+    out = jnp.einsum("bkgc,bkdc->bkgd", e, cache.v.astype(f32))
+    out += e_new[..., None] * v_new.astype(f32)[:, :, None]
+    out /= (jnp.sum(e, axis=-1) + e_new)[..., None]
+    out = out.reshape(b, 1, kv * g * hd).astype(x.dtype)
     y = dense_apply(params["wo"], out, compute_dtype=cfg.compute_dtype)
-    return y, new_cache, lora_h
+    return y, KVRow(k=k_new, v=v_new), lora_h
+
+
+def write_kv_rows(cache: KVCache, rows: KVRow) -> KVCache:
+    """Write each layer's new K/V row into the layer-stacked cache, in
+    place: one single-slot update per tensor.
+
+    ``cache`` leaves carry a leading layer axis R (k/v (R, B, Kv, Dh, C));
+    ``rows`` are (R, B, Kv, Dh).  Every decode step writes every layer, so
+    all layers share one length and one slot.
+    """
+    length = cache.length[0]
+    slot = (length % cache.k.shape[-1]).astype(jnp.int32)
+    r = cache.k.shape[0]
+    return KVCache(
+        k=jax.lax.dynamic_update_slice(cache.k, rows.k[..., None], (0, 0, 0, 0, slot)),
+        v=jax.lax.dynamic_update_slice(cache.v, rows.v[..., None], (0, 0, 0, 0, slot)),
+        pos=jax.lax.dynamic_update_slice(cache.pos, jnp.full((r, 1), length, jnp.int32), (0, slot)),
+        length=cache.length + 1,
+    )
 
 
 def cross_attn_apply(

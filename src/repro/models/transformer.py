@@ -12,7 +12,9 @@ stack runs under ``jax.lax.scan`` (one compiled period regardless of depth —
 scan body in ``jax.checkpoint`` for activation rematerialisation.
 
 Caches (decode) follow the same layout: a period-dict of per-position cache
-pytrees, each stacked over repeats, scanned alongside the params.
+pytrees, each stacked over repeats, read per repeat alongside the params
+(``stack_apply``: attention caches in place, new K/V rows written after the
+layer loop).
 """
 
 from __future__ import annotations
@@ -25,10 +27,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.attention import (
+    KVCache,
+    KVRow,
     attn_apply,
     attn_init,
     cross_attn_apply,
     init_kv_cache,
+    write_kv_rows,
 )
 from repro.models.layers import mlp_apply, mlp_init, norm_apply, norm_init
 from repro.models.moe import moe_apply, moe_init
@@ -246,23 +251,15 @@ def stack_apply(
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
-    if _UNROLL:
-        # cost-mode (REPRO_UNROLL=1): python loop so HLO cost analysis sees
-        # every repeat (XLA counts while bodies once; see launch/dryrun.py)
-        state = state0
-        new_caches_list = []
-        for r in range(repeats):
-            params_r = jax.tree.map(lambda a: a[r], stack_params)
-            cache_r = jax.tree.map(lambda a: a[r], caches) if caches is not None else None
-            state, nc = body(state, (params_r, cache_r))
-            if nc is not None:
-                new_caches_list.append(nc)
-        if caches is None:
-            return state, None
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches_list)
-        return state, stacked
-
     if caches is None:
+        if _UNROLL:
+            # cost-mode (REPRO_UNROLL=1): python loop so HLO cost analysis
+            # sees every repeat (XLA counts while bodies once; launch/dryrun.py)
+            state = state0
+            for r in range(repeats):
+                state, _ = body(state, (jax.tree.map(lambda a: a[r], stack_params), None))
+            return state, None
+
         # scan can't carry a None in xs leaves; substitute per-step None via
         # a length marker: replicate None structure by scanning params only.
         def body_nocache(state, params_slice):
@@ -272,21 +269,43 @@ def stack_apply(
         final, _ = jax.lax.scan(body_nocache, state0, stack_params, length=repeats)
         return final, None
 
-    # Decode: carry the stacked caches through a fori_loop and update slices
-    # in place.  A scan emitting new caches as ys holds BOTH the old stack
-    # (xs) and the new stack (ys) plus in-flight copies — ~3x cache in temp
-    # memory at decode_32k (§Perf iteration 9); while-loop carries alias.
-    def body_carry(r, carry):
-        state, caches_c = carry
-        params_r = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False), stack_params)
-        cache_r = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False), caches_c)
-        state, new_r = body(state, (params_r, cache_r))
-        caches_c = jax.tree.map(
-            lambda full, n: jax.lax.dynamic_update_index_in_dim(full, n, r, 0),
-            caches_c,
-            new_r,
-        )
-        return state, caches_c
+    # Decode: a fori_loop over the repeats.  Attention layers read their KV
+    # cache in place (a loop invariant, never copied out or written back)
+    # and hand back their new K/V row, which the carry gathers into an
+    # (R, ...) stack of rows; after the loop one update per tensor writes
+    # every layer's row into its slot (``write_kv_rows``).  SSM caches are
+    # rewritten whole by their nature: they ride in the carry and each
+    # layer's state is replaced in place.  While-loop carries alias; a scan
+    # emitting new caches as ys holds BOTH the old stack (xs) and the new
+    # stack (ys) plus in-flight copies — ~3x cache in temp memory at
+    # decode_32k (§Perf iteration 9).
+    def index(tree, r):
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False), tree)
 
-    final, new_caches = jax.lax.fori_loop(0, repeats, body_carry, (state0, caches))
+    is_kv = {n: isinstance(c, KVCache) for n, c in caches.items()}
+    carried0 = {  # per position: every repeat's new K/V row, or the SSM cache
+        n: KVRow(k=jnp.zeros(c.k.shape[:-1], c.k.dtype), v=jnp.zeros(c.v.shape[:-1], c.v.dtype))
+        if is_kv[n] else c
+        for n, c in caches.items()
+    }
+
+    def body_carry(r, carry):
+        state, carried = carry
+        read = {n: caches[n] if is_kv[n] else carried[n] for n in caches}
+        state, new_r = body(state, (index(stack_params, r), index(read, r)))
+        carried = jax.tree.map(
+            lambda full, n: jax.lax.dynamic_update_index_in_dim(full, n, r, 0), carried, new_r
+        )
+        return state, carried
+
+    carry = (state0, carried0)
+    if _UNROLL:  # cost-mode, as above
+        for r in range(repeats):
+            carry = body_carry(r, carry)
+    else:
+        carry = jax.lax.fori_loop(0, repeats, body_carry, carry)
+    final, carried = carry
+    new_caches = {
+        n: write_kv_rows(caches[n], carried[n]) if is_kv[n] else carried[n] for n in caches
+    }
     return final, new_caches

@@ -223,7 +223,7 @@ def batch_specs(mesh: Mesh, *, batch_shardable: bool = True, with_frontend: bool
 
 
 def cache_specs(cache_shape: Any, mesh: Mesh, *, batch_shardable: bool = True) -> Any:
-    """Decode cache: KV k/v (B, C, Kv, Dh) -> seq-sharded over model;
+    """Decode cache: KV k/v (B, Kv, Dh, C) -> seq (C) sharded over model;
     SSM conv (B, W-1, ch) -> ch over model; state (B,H,P,N) -> H over model.
     All have a leading stacked-repeats axis from scan-over-layers."""
     b = batch_axes(mesh) if batch_shardable else None
@@ -232,8 +232,8 @@ def cache_specs(cache_shape: Any, mesh: Mesh, *, batch_shardable: bool = True) -
         names = _path_strings(path)
         nd = len(leaf.shape)
         last = names[-1]
-        if last in ("k", "v") and nd == 5:  # (R, B, C, Kv, Dh)
-            return P(None, b, "model", None, None)
+        if last in ("k", "v") and nd == 5:  # (R, B, Kv, Dh, C)
+            return P(None, b, None, None, "model")
         if last == "pos":  # (R, C)
             return P(None, "model")
         if last == "length":

@@ -1,5 +1,7 @@
 """The main path's Pallas kernels compile for a TPU v5e chip at published
-widths (GPT-2's V=50,257, Table I's public batch P=256, a cohort of N=10).
+widths (GPT-2's V=50,257, Table I's public batch P=256, a cohort of N=10),
+and so does the serving decode step, which reads each layer's KV cache
+where it lies: its temporary memory stays below one layer's K slice.
 
 Nothing runs here: each kernel is lowered against a v5e:2x2 topology that is
 DESCRIBED, not attached, and compiled by the chip's own compiler, which
@@ -15,12 +17,17 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.configs.gpt2_paper import GPT2_SMALL
 from repro.kernels.distill_kl import distill_kl_pallas
 from repro.kernels.sparse_agg import (
     scatter_wire_sums_dequant_pallas,
     scatter_wire_sums_pallas,
 )
 from repro.kernels.topk_select import topk_mask_dynamic_pallas, topk_mask_pallas
+from repro.lora import split_lora
+from repro.models import init, init_cache
+from repro.serve import make_decode_step
+from repro.serve.steps import make_stacked_decode_step
 
 V, P, N = 50_257, 256, 10
 K_CAPS = (512, 4096)
@@ -96,3 +103,35 @@ def test_distill_kl_compiles(one_chip, rows):
         ((rows, V), jnp.float32), ((rows, V), jnp.float32), temperature=2.0,
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("mode", ["stacked", "single"])
+def test_decode_step_moves_no_layer_slice(one_chip, mode):
+    # GPT-2 small's widths and the served cell's batch 8 and 1,024-slot
+    # cache, in two layers: no whole layer slice of the cache is copied out,
+    # relaid or written back, and the donated cache comes back in its own
+    # buffers.  (The CPU compiler materialises every per-layer operand of a
+    # dot, so only the chip's compiler can show this.)
+    cfg, b, c = GPT2_SMALL.with_overrides(num_layers=2), 8, 1024
+
+    def spec(tree, lead=()):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((*lead, *a.shape), a.dtype, sharding=one_chip), tree
+        )
+
+    params = spec(jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg)))
+    cache = spec(jax.eval_shape(lambda: init_cache(cfg, b, c)))
+    token = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    if mode == "single":
+        step = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+        compiled = step.lower(params, cache, token).compile()
+    else:
+        lora, frozen = split_lora(params)
+        step = jax.jit(make_stacked_decode_step(cfg), donate_argnums=(3,))
+        slots = token  # (B,) int32 like a token: each request's adapter slot
+        compiled = step.lower(frozen, spec(lora, (4,)), slots, cache, token).compile()
+    mem = compiled.memory_analysis()
+    layer_k_bytes = b * c * cfg.num_kv_heads * cfg.head_dim * 4
+    assert mem.temp_size_in_bytes < layer_k_bytes, (mem.temp_size_in_bytes, layer_k_bytes)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes, (mem.alias_size_in_bytes, cache_bytes)

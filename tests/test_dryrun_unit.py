@@ -76,9 +76,9 @@ def test_decode_cache_ring_bounded_by_window():
     cfg = arch_shape_config("command-r-35b", shape)
     specs = input_specs(cfg, shape)
     k = specs["cache"]["layers"]["pos0"].k
-    assert k.shape[2] == 4096  # ring buffer, not 524288
+    assert k.shape[-1] == 4096  # ring buffer, not 524288
     cfg_j = arch_shape_config("jamba-1.5-large-398b", shape)
     specs_j = input_specs(cfg_j, shape)
     # jamba attention position carries the full-length cache
     attn_pos = f"pos{cfg_j.attn_offset}"
-    assert specs_j["cache"]["layers"][attn_pos].k.shape[2] == 524288
+    assert specs_j["cache"]["layers"][attn_pos].k.shape[-1] == 524288

@@ -62,7 +62,7 @@ def test_smoke_train_step(arch):
 
 @pytest.mark.parametrize(
     "arch", ["stablelm-1.6b", "mamba2-130m", "jamba-1.5-large-398b", "granite-moe-1b-a400m",
-             "seamless-m4t-large-v2", "internvl2-76b"]
+             "seamless-m4t-large-v2", "internvl2-76b", "gpt2-paper"]
 )
 def test_decode_matches_forward(arch):
     """KV-cache / SSM-state decode reproduces the teacher-forced forward."""
@@ -96,14 +96,15 @@ def test_decode_matches_forward(arch):
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full), rtol=2e-3, atol=2e-3)
 
 
-def test_sliding_window_decode_matches():
+@pytest.mark.parametrize("batch", [1, 3])
+def test_sliding_window_decode_matches(batch):
     cfg = get_smoke_config("yi-9b").with_overrides(sliding_window=6)
     params = init(jax.random.PRNGKey(0), cfg)
     steps = 16
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, steps), 0, cfg.vocab_size)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, steps), 0, cfg.vocab_size)
     full, _ = forward(params, cfg, {"tokens": tokens})
-    cache = init_cache(cfg, 1, 64)  # ring buffer sized by window
-    assert cache["layers"]["pos0"].k.shape[2] == 6
+    cache = init_cache(cfg, batch, 64)  # ring buffer sized by window
+    assert cache["layers"]["pos0"].k.shape[-1] == 6
     outs = []
     for t in range(steps):
         lg, cache = decode_step(params, cfg, cache, tokens[:, t])
