@@ -9,12 +9,15 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import INPUT_SHAPES, LoRAConfig, ModelConfig, MoEConfig, ShapeConfig, SSMConfig
+from repro.configs.base import (
+    INPUT_SHAPES, LoRAConfig, MLAConfig, ModelConfig, MoEConfig, ShapeConfig, SSMConfig,
+)
 
 __all__ = [
     "ARCHITECTURES",
     "INPUT_SHAPES",
     "LoRAConfig",
+    "MLAConfig",
     "ModelConfig",
     "MoEConfig",
     "SSMConfig",
@@ -32,7 +35,7 @@ ARCHITECTURES: dict[str, str] = {
     "internvl2-76b": "internvl2_76b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "yi-9b": "yi_9b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "command-r-35b": "command_r_35b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     # the paper's own models

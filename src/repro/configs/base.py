@@ -28,6 +28,34 @@ class MoEConfig:
     d_ff: int  # per-expert hidden width
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01  # load-balance loss weight (Switch/GShard)
+    # softmax: gates are the softmax of the router logits.  sigmoid
+    # (DeepSeek-V3's noaux_tc): gates are sigmoid(logits); experts are
+    # SELECTED by sigmoid(logits) + score_bias (a per-expert leaf), weighted
+    # without the bias.  Either way the top-k gates are renormalised to sum
+    # to one, then scaled by routed_scale.
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scale: float = 1.0
+    shared_d_ff: int = 0  # width of the always-on shared experts (0 = none)
+    # (count, first id) of the routed experts this chip holds, for expert
+    # parallelism: the router still scores all num_experts, and the layer
+    # returns the held experts' part of the result.  None = all of them.
+    experts_held: tuple[int, int] | None = None
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.experts_held or (self.num_experts, 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without query
+    compression: keys and values come from one ``kv_rank`` latent per
+    token plus a ``rope_dim`` RoPE key that all heads share."""
+
+    kv_rank: int  # kv_lora_rank: the latent's width
+    nope_dim: int  # qk_nope_head_dim: per-head key/query width without RoPE
+    rope_dim: int  # qk_rope_head_dim: the shared RoPE key's width
+    v_dim: int  # v_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +91,10 @@ class ModelConfig:
     vocab_size: int
     head_dim: int | None = None  # default d_model // num_heads
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
+    # leading layers with a dense MLP of width d_ff ahead of the (MoE)
+    # period stack (DeepSeek-V3's first_k_dense_replace)
+    first_dense_layers: int = 0
     ssm: SSMConfig | None = None
     # hybrid: layer i is attention iff i % attn_every == attn_offset,
     # else Mamba.  Jamba uses 1:7 (one attn per 8 layers).
@@ -83,6 +115,7 @@ class ModelConfig:
     positional: Literal["rope", "learned", "none"] = "rope"
     rope_theta: float = 10000.0
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
+    norm_eps: float = 1e-6
     activation: Literal["swiglu", "gelu"] = "swiglu"
     use_bias: bool = False
     tie_embeddings: bool = False
@@ -135,6 +168,11 @@ class ModelConfig:
     # ---- analytic parameter counts (for roofline MODEL_FLOPS = 6·N·D) ----
 
     def _attn_params(self) -> int:
+        if self.mla is not None:
+            m, h = self.mla, self.num_heads
+            return (self.d_model * h * (m.nope_dim + m.rope_dim)
+                    + self.d_model * (m.kv_rank + m.rope_dim) + m.kv_rank
+                    + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * self.d_model)
         hd = self.head_dim
         q = self.d_model * self.num_heads * hd
         kv = 2 * self.d_model * self.num_kv_heads * hd
@@ -151,7 +189,8 @@ class ModelConfig:
         per_expert = mult * self.d_model * self.moe.d_ff
         router = self.d_model * self.moe.num_experts
         n = self.moe.top_k if active_only else self.moe.num_experts
-        return n * per_expert + router
+        shared = mult * self.d_model * self.moe.shared_d_ff
+        return n * per_expert + router + shared
 
     def _ssm_params(self) -> int:
         assert self.ssm is not None
@@ -182,7 +221,7 @@ class ModelConfig:
             if self.family == "ssm":
                 # Mamba2 blocks have no separate MLP
                 continue
-            if self.is_moe_layer(i):
+            if self.is_moe_layer(i) and i >= self.first_dense_layers:
                 layers += self._moe_mlp_params(active_only)
             else:
                 layers += self._dense_mlp_params()

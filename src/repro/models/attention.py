@@ -18,6 +18,15 @@ Sharding-relevant layout decisions (see DESIGN §4):
 
 RoPE is applied at *write* time for keys (rotation by absolute position),
 so cached keys never need re-rotation (relative property preserved).
+
+Multi-head latent attention (``cfg.mla``, DeepSeek-V2/V3 without query
+compression) caches one row per token for all heads: the RMS-normalised
+latent ``c`` (``kv_rank`` wide) and the RoPE key that every head shares,
+``(B, kv_rank + rope_dim, C)`` with C minor (``LatentCache``).  A
+full-sequence pass expands keys ``[c·W_uk, k_pe]`` and values ``c·W_uv``
+per head; decode uses the absorbed form: ``W_uk`` is folded into the
+query, scores and the weighted sum read the latent rows as they are, and
+``W_uv`` (with the ``v`` adapter) is applied to the weighted latent.
 """
 
 from __future__ import annotations
@@ -28,11 +37,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_apply, dense_init
+from repro.models.layers import apply_rope, dense_apply, dense_init, norm_apply, norm_init
 
 __all__ = [
-    "KVCache", "KVRow", "attn_init", "attn_apply", "init_kv_cache", "write_kv_rows",
-    "cross_attn_apply",
+    "KVCache", "KVRow", "LatentCache", "LatentRow", "attn_init", "attn_apply",
+    "init_kv_cache", "mla_init", "mla_apply", "init_latent_cache", "empty_rows",
+    "write_kv_rows", "cross_attn_apply",
 ]
 
 _NEG_INF = -1e30
@@ -50,6 +60,33 @@ class KVRow(NamedTuple):
 
     k: jax.Array  # (B, Kv, Dh) — RoPE already applied
     v: jax.Array  # (B, Kv, Dh)
+
+
+class LatentCache(NamedTuple):
+    latent: jax.Array  # (B, kv_rank + rope_dim, C): normalised latent, then rotated RoPE key
+    pos: jax.Array  # (C,) absolute position of each slot, -1 = empty
+    length: jax.Array  # () int32 — tokens seen so far (absolute)
+    # decode inside a layer loop: ``latent`` is the whole layer stack
+    # (R, B, W, C) and ``layer`` the index of this layer, so that the two
+    # reads (scores over all W rows, the weighted sum over the latent's)
+    # each slice the stack where it lies; one slice shared by both would be
+    # copied out whole
+    layer: jax.Array | None = None
+
+
+class LatentRow(NamedTuple):
+    """One decode step's new latent row of one MLA layer."""
+
+    latent: jax.Array  # (B, kv_rank + rope_dim)
+
+
+def empty_rows(cache: KVCache | LatentCache):
+    """Zero rows of a layer-stacked cache's row type: one per layer and
+    request, the cache's shape without its last (slot) axis."""
+    if isinstance(cache, LatentCache):
+        return LatentRow(latent=jnp.zeros(cache.latent.shape[:-1], cache.latent.dtype))
+    return KVRow(k=jnp.zeros(cache.k.shape[:-1], cache.k.dtype),
+                 v=jnp.zeros(cache.v.shape[:-1], cache.v.dtype))
 
 
 def attn_init(rng: jax.Array, cfg: ModelConfig, *, cross: bool = False) -> dict:
@@ -288,20 +325,25 @@ def attn_apply(
     return y, KVRow(k=k_new, v=v_new), lora_h
 
 
-def write_kv_rows(cache: KVCache, rows: KVRow) -> KVCache:
-    """Write each layer's new K/V row into the layer-stacked cache, in
-    place: one single-slot update per tensor.
+def write_kv_rows(cache: KVCache | LatentCache, rows: KVRow | LatentRow):
+    """Write each layer's new K/V (or latent) row into the layer-stacked
+    cache, in place: one single-slot update per tensor.
 
-    ``cache`` leaves carry a leading layer axis R (k/v (R, B, Kv, Dh, C));
-    ``rows`` are (R, B, Kv, Dh).  Every decode step writes every layer, so
-    all layers share one length and one slot.
+    ``cache`` leaves carry a leading layer axis R (k/v (R, B, Kv, Dh, C),
+    latent (R, B, kv_rank + rope_dim, C)); ``rows`` are the same without
+    C.  Every decode step writes every layer, so all layers share one
+    length and one slot.
     """
     length = cache.length[0]
-    slot = (length % cache.k.shape[-1]).astype(jnp.int32)
-    r = cache.k.shape[0]
-    return KVCache(
-        k=jax.lax.dynamic_update_slice(cache.k, rows.k[..., None], (0, 0, 0, 0, slot)),
-        v=jax.lax.dynamic_update_slice(cache.v, rows.v[..., None], (0, 0, 0, 0, slot)),
+    slot = (length % cache.pos.shape[-1]).astype(jnp.int32)
+    r = cache.pos.shape[0]
+    written = {
+        f: jax.lax.dynamic_update_slice(
+            getattr(cache, f), row[..., None], (0,) * row.ndim + (slot,))
+        for f, row in rows._asdict().items()
+    }
+    return cache._replace(
+        **written,
         pos=jax.lax.dynamic_update_slice(cache.pos, jnp.full((r, 1), length, jnp.int32), (0, slot)),
         length=cache.length + 1,
     )
@@ -333,3 +375,135 @@ def cross_attn_apply(
     probs = jax.nn.softmax(scores, axis=-1)
     out = _gqa_output(probs, v, cfg).astype(x.dtype)
     return dense_apply(params["wo"], out, compute_dtype=cfg.compute_dtype)
+
+
+# ---- multi-head latent attention (cfg.mla) --------------------------------
+
+
+def mla_init(rng: jax.Array, cfg: ModelConfig) -> dict:
+    """q_proj ``wq``; kv_a_proj_with_mqa ``wkv_a`` (latent, then the shared
+    RoPE key); kv_a_layernorm ``kv_norm``; kv_b_proj split into its key half
+    ``wk_b`` (W_uk) and value half ``wv_b`` (W_uv); o_proj ``wo``."""
+    m, h, d, dt = cfg.mla, cfg.num_heads, cfg.d_model, cfg.param_dtype
+    keys = jax.random.split(rng, 5)
+    return {
+        "wq": dense_init(keys[0], d, h * (m.nope_dim + m.rope_dim), dtype=dt),
+        "wkv_a": dense_init(keys[1], d, m.kv_rank + m.rope_dim, dtype=dt),
+        "kv_norm": norm_init(m.kv_rank, kind="rmsnorm", dtype=dt),
+        "wk_b": dense_init(keys[2], m.kv_rank, h * m.nope_dim, dtype=dt),
+        "wv_b": dense_init(keys[3], m.kv_rank, h * m.v_dim, dtype=dt),
+        "wo": dense_init(keys[4], h * m.v_dim, d, dtype=dt),
+    }
+
+
+def init_latent_cache(
+    cfg: ModelConfig, batch: int, cache_len: int, *, dtype: str | None = None
+) -> LatentCache:
+    dt = jnp.dtype(dtype or cfg.compute_dtype)
+    width = cfg.mla.kv_rank + cfg.mla.rope_dim
+    return LatentCache(
+        latent=jnp.zeros((batch, width, cache_len), dt),
+        pos=jnp.full((cache_len,), -1, jnp.int32),
+        length=jnp.zeros((), jnp.int32),
+    )
+
+
+def _lora_per_head(lora_p: dict, o: jax.Array, *, heads: int, alpha: float, rank: int,
+                   compute_dtype: str) -> jax.Array:
+    """The LoRA term ``(alpha/r)·(o·A)·B`` of a projection whose output is
+    split into heads, for an input ``o (B, H, i)`` that differs per head:
+    head h reads only its own columns of B.  Shared (A (i, r)) or
+    per-request (A (B, i, r)) adapters, as ``_lora_delta``."""
+    cd = jnp.dtype(compute_dtype)
+    a, bm = lora_p["A"].astype(cd), lora_p["B"].astype(cd)
+    if a.ndim == 3:
+        h = jnp.einsum("bhi,bir->bhr", o.astype(cd), a)
+        bm = bm.reshape(bm.shape[0], bm.shape[1], heads, -1)
+        return jnp.einsum("bhr,brhv->bhv", h, bm) * (alpha / rank)
+    h = jnp.einsum("bhi,ir->bhr", o.astype(cd), a)
+    bm = bm.reshape(bm.shape[0], heads, -1)
+    return jnp.einsum("bhr,rhv->bhv", h, bm) * (alpha / rank)
+
+
+def mla_apply(
+    params: dict,
+    x: jax.Array,
+    cfg: ModelConfig,
+    *,
+    positions: jax.Array,
+    window: int | None = None,
+    cache: LatentCache | None = None,
+    lora: dict | None = None,
+    causal: bool = True,
+) -> tuple[jax.Array, LatentRow | None, jax.Array | None]:
+    """Multi-head latent attention.  Returns (output, new latent row or
+    None, lora_h), as ``attn_apply``.  LoRA ``q`` adapts ``wq``; ``v``
+    adapts the value half of kv_b_proj, ``c·(W_uv + (alpha/r)·A·B)``,
+    which is linear in the latent and so exact in the absorbed form."""
+    m, heads = cfg.mla, cfg.num_heads
+    cd = cfg.compute_dtype
+    b, s, _ = x.shape
+    q_flat, h_q = _project(params, x, "q", cfg, lora)
+    q = q_flat.reshape(b, s, heads, m.nope_dim + m.rope_dim)
+    q_nope, q_pe = q[..., :m.nope_dim], q[..., m.nope_dim:]
+    kv = dense_apply(params["wkv_a"], x, compute_dtype=cd)
+    c = norm_apply(params["kv_norm"], kv[..., :m.kv_rank], kind="rmsnorm", eps=cfg.norm_eps)
+    q_pe = apply_rope(q_pe, positions, theta=cfg.rope_theta)
+    k_pe = apply_rope(kv[..., None, m.kv_rank:], positions, theta=cfg.rope_theta)[..., 0, :]
+    v_lora = lora.get("v") if lora is not None else None
+    scale = (m.nope_dim + m.rope_dim) ** -0.5
+
+    if cache is None:
+        # ---- full sequence: keys and values expanded per head ----
+        k_nope = dense_apply(params["wk_b"], c, compute_dtype=cd).reshape(b, s, heads, m.nope_dim)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, :, None], (b, s, heads, m.rope_dim))], axis=-1)
+        v = dense_apply(params["wv_b"], c, compute_dtype=cd)
+        if v_lora is not None:
+            v = v + _lora_delta(v_lora, c, alpha=cfg.lora.alpha, rank=cfg.lora.rank,
+                                compute_dtype=cd)[0]
+        v = v.reshape(b, s, heads, m.v_dim)
+        qk = jnp.concatenate([q_nope, q_pe], axis=-1)
+        attend = _chunked_attention if s >= 2 * Q_CHUNK else _dense_attention
+        out = attend(qk, k, v, cfg, positions, window, causal)
+        y = dense_apply(params["wo"], out.astype(x.dtype), compute_dtype=cd)
+        return y, None, h_q
+
+    # ---- decode, absorbed: one new token against one layer's latent cache ----
+    assert s == 1, "decode mode expects one new token"
+    f32 = jnp.float32
+    w_uk = params["wk_b"]["w"].reshape(m.kv_rank, heads, m.nope_dim)
+    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(f32), w_uk.astype(f32))
+    q_all = jnp.concatenate([q_lat, q_pe[:, 0].astype(f32)], axis=-1)  # (B, H, 576)
+    row = jnp.concatenate([c[:, 0], k_pe[:, 0]], axis=-1).astype(cache.latent.dtype)
+    # the cache is read where it lies: scores over all 576 values of a
+    # slot, the weighted sum over the latent's 512
+    if cache.layer is None:
+        latent, values = cache.latent, cache.latent[:, :m.kv_rank]
+    else:
+        stack = cache.latent
+        latent = jax.lax.dynamic_index_in_dim(stack, cache.layer, 0, keepdims=False)
+        values = jax.lax.dynamic_slice(
+            stack, (cache.layer, 0, 0, 0), (1,) + stack.shape[1:2] + (m.kv_rank,)
+            + stack.shape[3:])[0]
+    scores = jnp.einsum("bhc,bct->bht", q_all, latent.astype(f32)) * scale
+    score_new = jnp.einsum("bhc,bc->bh", q_all, row.astype(f32)) * scale
+    slot = cache.length % cache.pos.shape[0]
+    valid = (cache.pos >= 0) & (jnp.arange(cache.pos.shape[0]) != slot)
+    if window is not None:
+        valid &= cache.pos > cache.length - window
+    scores = jnp.where(valid, scores, _NEG_INF)
+    top = jnp.maximum(jnp.max(scores, axis=-1), score_new)
+    e = jnp.exp(scores - top[..., None])
+    e_new = jnp.exp(score_new - top)
+    o_lat = jnp.einsum("bht,bct->bhc", e, values.astype(f32))
+    o_lat += e_new[..., None] * row[:, None, :m.kv_rank].astype(f32)
+    o_lat /= (jnp.sum(e, axis=-1) + e_new)[..., None]  # (B, H, kv_rank)
+    w_uv = params["wv_b"]["w"].reshape(m.kv_rank, heads, m.v_dim)
+    out = jnp.einsum("bhc,chv->bhv", o_lat.astype(jnp.dtype(cd)), w_uv.astype(jnp.dtype(cd)))
+    if v_lora is not None:
+        out = out + _lora_per_head(v_lora, o_lat, heads=heads, alpha=cfg.lora.alpha,
+                                   rank=cfg.lora.rank, compute_dtype=cd)
+    out = out.reshape(b, 1, heads * m.v_dim).astype(x.dtype)
+    y = dense_apply(params["wo"], out, compute_dtype=cd)
+    return y, LatentRow(latent=row), h_q

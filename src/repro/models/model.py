@@ -27,6 +27,7 @@ from repro.configs.base import ModelConfig
 from repro.models.frontends import synth_frontend_embeddings
 from repro.models.layers import embedding_init, norm_apply, norm_init
 from repro.models.transformer import (
+    init_moe_stats,
     init_stack_cache,
     stack_apply,
     stack_init,
@@ -52,13 +53,30 @@ def input_token_len(cfg: ModelConfig, seq_len: int) -> int:
     return seq_len
 
 
+def _stacks(cfg: ModelConfig) -> list[tuple[str, ModelConfig, int]]:
+    """(params key, config, layers) of each decoder layer stack, in order:
+    the leading dense layers (``cfg.first_dense_layers``, DeepSeek-V3's
+    first_k_dense_replace) as a stack of their own, then the period stack.
+    A decode cache keeps each stack's layers under ``<key>_layers``."""
+    k = cfg.first_dense_layers
+    if not k:
+        return [("stack", cfg, cfg.num_layers)]
+    return [("prefix", cfg.with_overrides(moe=None), k), ("stack", cfg, cfg.num_layers - k)]
+
+
+def _cache_key(name: str) -> str:
+    return "layers" if name == "stack" else f"{name}_layers"
+
+
 def init(rng: jax.Array, cfg: ModelConfig) -> dict:
     keys = jax.random.split(rng, 6)
     params: dict[str, Any] = {
         "embed": embedding_init(keys[0], cfg.vocab_size, cfg.d_model, dtype=cfg.param_dtype),
         "final_norm": norm_init(cfg.d_model, kind=cfg.norm, dtype=cfg.param_dtype),
-        "stack": stack_init(keys[1], cfg, cfg.num_layers, cross=cfg.cross_attention),
     }
+    for i, (name, c, n) in enumerate(_stacks(cfg)):
+        key = keys[1] if name == "stack" else jax.random.fold_in(keys[1], i + 1)
+        params[name] = stack_init(key, c, n, cross=cfg.cross_attention)
     if cfg.positional == "learned":
         params["pos_embed"] = embedding_init(keys[2], cfg.max_seq_len, cfg.d_model, dtype=cfg.param_dtype)
     if not cfg.tie_embeddings:
@@ -185,20 +203,23 @@ def backbone(
         pos = jnp.arange(s_text, dtype=jnp.int32)
         x = _embed_tokens(params, cfg, tokens, pos)
 
-    st, _ = stack_apply(
-        params["stack"], x, cfg, cfg.num_layers, positions=pos, window=window, enc_out=enc_out
-    )
-    x_out = st.x
+    moe_aux, lora_h = jnp.zeros((), jnp.float32), None
+    for name, c, n in _stacks(cfg):
+        st, _ = stack_apply(
+            params[name], x, c, n, positions=pos, window=window, enc_out=enc_out
+        )
+        x, moe_aux = st.x, moe_aux + st.moe_aux
+        lora_h = st.lora_h if st.lora_h is not None else lora_h
+    x_out = x
     if cfg.family == "vlm":
         x_out = x_out[:, frontend.shape[1] :]  # text region only
-    lora_h = st.lora_h
     # The SSM fallback projection pools over the FULL normalized sequence, so
     # that path must norm every position even under last_only.
     need_fallback_h = lora_h is None and "lora_head" in params
     if last_only and not need_fallback_h:
-        h = norm_apply(params["final_norm"], x_out[:, -1:], kind=cfg.norm)
+        h = norm_apply(params["final_norm"], x_out[:, -1:], kind=cfg.norm, eps=cfg.norm_eps)
     else:
-        h = norm_apply(params["final_norm"], x_out, kind=cfg.norm)
+        h = norm_apply(params["final_norm"], x_out, kind=cfg.norm, eps=cfg.norm_eps)
         if need_fallback_h:
             # attention-free families (SSM) have no q/v adapters; the paper's
             # projection h = A·x (eq. 8) comes from the head adapter instead —
@@ -210,7 +231,7 @@ def backbone(
             )
         if last_only:
             h = h[:, -1:]
-    return h, Aux(moe_aux=st.moe_aux, lora_h=lora_h)
+    return h, Aux(moe_aux=moe_aux, lora_h=lora_h)
 
 
 def forward(
@@ -250,13 +271,22 @@ def init_cache(
     window: int | None = None,
     enc_out: jax.Array | None = None,
 ) -> dict:
-    """Decode cache: per-layer KV/SSM caches + absolute length + optional
-    fixed encoder output (audio cross-attention)."""
+    """Decode cache: per-layer KV/latent/SSM caches of each stack +
+    absolute length + optional fixed encoder output (audio cross-attention)
+    + the MoE layers' routing stats (``moe``, per MoE position: ``counts``
+    (repeats, 3) int32 summed over the steps: pairs routed to held experts,
+    held experts used, pairs dropped; ``experts`` (repeats, B, k, C) int32:
+    the experts each request's token in each slot was routed to)."""
     window = window if window is not None else cfg.sliding_window
     cache = {
-        "layers": init_stack_cache(cfg, cfg.num_layers, batch, cache_len, window=window),
-        "length": jnp.zeros((), jnp.int32),
+        _cache_key(name): init_stack_cache(c, n, batch, cache_len, window=window)
+        for name, c, n in _stacks(cfg)
     }
+    cache["length"] = jnp.zeros((), jnp.int32)
+    if cfg.moe is not None:
+        _, c, n = _stacks(cfg)[-1]
+        slots = min(cache_len, window) if window is not None else cache_len
+        cache["moe"] = init_moe_stats(c, n, batch, slots)
     if cfg.family == "audio":
         if enc_out is None:
             enc_out = jnp.zeros((batch, cfg.frontend_len, cfg.d_model), jnp.dtype(cfg.compute_dtype))
@@ -280,21 +310,29 @@ def decode_step(
     x = _embed_tokens(params, cfg, token[:, None], pos)
     enc_out = cache.get("enc_out")
 
-    st, new_layer_caches = stack_apply(
-        params["stack"],
-        x,
-        cfg,
-        cfg.num_layers,
-        positions=pos,
-        window=window,
-        caches=cache["layers"],
-        enc_out=enc_out,
-    )
-    h = norm_apply(params["final_norm"], st.x, kind=cfg.norm)
+    new_cache = dict(cache)
+    for name, c, n in _stacks(cfg):
+        st, new_cache[_cache_key(name)] = stack_apply(
+            params[name],
+            x,
+            c,
+            n,
+            positions=pos,
+            window=window,
+            caches=cache[_cache_key(name)],
+            enc_out=enc_out,
+        )
+        x = st.x
+    if st.moe_stats is not None:
+        new_cache["moe"] = {
+            n: {"counts": cache["moe"][n]["counts"] + s["counts"],
+                "experts": jax.lax.dynamic_update_slice(
+                    cache["moe"][n]["experts"], s["experts"][..., None],
+                    (0, 0, 0, length % cache["moe"][n]["experts"].shape[-1]))}
+            for n, s in st.moe_stats.items()}
+    h = norm_apply(params["final_norm"], x, kind=cfg.norm, eps=cfg.norm_eps)
     logits = _lm_logits(params, cfg, h)[:, 0]  # (B, V)
 
-    new_cache = dict(cache)
-    new_cache["layers"] = new_layer_caches
     new_cache["length"] = length + 1
     return logits, new_cache
 

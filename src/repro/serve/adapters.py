@@ -42,7 +42,9 @@ __all__ = [
 
 
 def _is_stack_path(path) -> bool:
-    return "stack" in path_strings(path)
+    """Leaves of a layer stack (``stack``, and the leading dense layers'
+    ``prefix``) carry a leading repeats axis."""
+    return not {"stack", "prefix"}.isdisjoint(path_strings(path))
 
 
 def slab_init(like: Any, slots: int) -> Any:

@@ -123,7 +123,8 @@ class ServeSession:
 
     # -- decode-cache lifecycle -----------------------------------------
     def reset(self, *, frontend: jax.Array | None = None) -> None:
-        """Fresh decode cache (and encoder pass for audio families)."""
+        """Fresh decode cache (and encoder pass for audio families).  The
+        MoE routing counters carry over: they count the whole session."""
         mc = self.cfg.model
         with TraceAnnotation("serve.reset", batch=self._batch):
             enc_out = None
@@ -131,10 +132,14 @@ class ServeSession:
                 if frontend is None:
                     frontend = synth_frontend_embeddings(mc, self.cfg.batch)
                 enc_out = _run_encoder(self.params, mc, frontend)
+            moe = (self._cache or {}).get("moe")
             self._cache = init_cache(
                 mc, self.cfg.batch, self.cfg.cache_len,
                 window=self.cfg.window, enc_out=enc_out,
             )
+            if moe is not None:
+                for n, stats in moe.items():
+                    self._cache["moe"][n]["counts"] = stats["counts"]
         self._logits = None
         self._pos = 0
 
@@ -211,12 +216,28 @@ class ServeSession:
             out[name] = int(size()) if callable(size) else -1
         return out
 
+    def routes(self) -> dict | None:
+        """The experts each MoE layer routed each request's token in each
+        cache slot to, since the last ``reset``: per MoE position, (layers,
+        B, k, C) int32 device arrays; None for a model without experts.
+        Read them before the next ``step``, which donates the cache."""
+        if self._cache is None or "moe" not in self._cache:
+            return None
+        return {n: m["experts"] for n, m in self._cache["moe"].items()}
+
     def stats(self) -> dict:
         s = {
             "first_step_s": dict(self._first_s),
             "executables": self.executables(),
             "attached": self.attached,
         }
+        if self._cache is not None and "moe" in self._cache:
+            # per MoE layer over the session's decode steps: pairs routed to
+            # held experts, held experts used (summed over steps), pairs dropped
+            counts = np.concatenate([np.asarray(m["counts"]) for m in self._cache["moe"].values()])
+            s["moe_routed_pairs"] = counts[:, 0].tolist()
+            s["moe_experts_used"] = counts[:, 1].tolist()
+            s["moe_dropped_pairs"] = int(counts[:, 2].sum())
         if self.adapters is not None:
             s["adapter_cache"] = self.adapters.stats.as_dict()
             s["adapter_slots"] = self.adapters.slots

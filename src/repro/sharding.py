@@ -114,6 +114,12 @@ def _param_spec_for(path: tuple[str, ...], shape: tuple[int, ...], mesh: Mesh) -
             return spec(fsdp, "model")
         if parent == "wo":
             return spec("model", fsdp)
+        # MLA: the latent projection is shared by all heads; kv_b_proj's
+        # halves are head-sharded like wq
+        if parent == "wkv_a":
+            return spec(fsdp, None)
+        if parent in ("wk_b", "wv_b"):
+            return spec(None, "model")
 
     # ---- LoRA ----
     if name == "A":
@@ -124,6 +130,8 @@ def _param_spec_for(path: tuple[str, ...], shape: tuple[int, ...], mesh: Mesh) -
     # ---- MoE ----
     if parent == "router":
         return spec(fsdp, None) if core_ndim == 2 else spec(None)
+    if name == "score_bias":
+        return spec(None)
     if name in ("up", "gate", "down") and core_ndim == 3:
         # 2D weight-stationary sharding (§Perf iteration 7): experts over
         # model, per-expert F over fsdp.  The expert einsums then need NO
@@ -223,7 +231,8 @@ def batch_specs(mesh: Mesh, *, batch_shardable: bool = True, with_frontend: bool
 
 
 def cache_specs(cache_shape: Any, mesh: Mesh, *, batch_shardable: bool = True) -> Any:
-    """Decode cache: KV k/v (B, Kv, Dh, C) -> seq (C) sharded over model;
+    """Decode cache: KV k/v (B, Kv, Dh, C) and MLA latent (B, W, C) -> seq
+    (C) sharded over model;
     SSM conv (B, W-1, ch) -> ch over model; state (B,H,P,N) -> H over model.
     All have a leading stacked-repeats axis from scan-over-layers."""
     b = batch_axes(mesh) if batch_shardable else None
@@ -234,6 +243,8 @@ def cache_specs(cache_shape: Any, mesh: Mesh, *, batch_shardable: bool = True) -
         last = names[-1]
         if last in ("k", "v") and nd == 5:  # (R, B, Kv, Dh, C)
             return P(None, b, None, None, "model")
+        if last == "latent" and nd == 4:  # (R, B, kv_rank + rope_dim, C)
+            return P(None, b, None, "model")
         if last == "pos":  # (R, C)
             return P(None, "model")
         if last == "length":

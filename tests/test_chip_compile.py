@@ -1,7 +1,9 @@
 """The main path's Pallas kernels compile for a TPU v5e chip at published
 widths (GPT-2's V=50,257, Table I's public batch P=256, a cohort of N=10),
 and so does the serving decode step, which reads each layer's KV cache
-where it lies: its temporary memory stays below one layer's K slice.
+where it lies: its temporary memory stays below one layer's K slice; the
+same holds for Moonlight's latent cache, whose held experts run as grouped
+products.
 
 Nothing runs here: each kernel is lowered against a v5e:2x2 topology that is
 DESCRIBED, not attached, and compiled by the chip's own compiler, which
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.gpt2_paper import GPT2_SMALL
+from repro.configs.moonlight_16b_a3b import CONFIG as MOONLIGHT
 from repro.kernels.distill_kl import distill_kl_pallas
 from repro.kernels.sparse_agg import (
     scatter_wire_sums_dequant_pallas,
@@ -135,3 +138,36 @@ def test_decode_step_moves_no_layer_slice(one_chip, mode):
     assert mem.temp_size_in_bytes < layer_k_bytes, (mem.temp_size_in_bytes, layer_k_bytes)
     cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes >= cache_bytes, (mem.alias_size_in_bytes, cache_bytes)
+
+
+def test_latent_decode_step_reads_the_cache_in_place(one_chip):
+    # Moonlight's published widths, the cell's batch 128 and 1,024 slots,
+    # 8 of 64 experts held, in one dense and one MoE layer: the latent stack
+    # is read where it lies by both of its reads (scores, weighted sum), and
+    # the held experts' grouped products compile to the chip's ragged dot
+    import dataclasses
+
+    cfg = MOONLIGHT.with_overrides(
+        num_layers=2, vocab_size=20_480, param_dtype="float32", compute_dtype="float32",
+        moe=dataclasses.replace(MOONLIGHT.moe, experts_held=(8, 24)))
+    b, c = 128, 1024
+
+    def spec(tree, lead=()):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((*lead, *a.shape), a.dtype, sharding=one_chip), tree
+        )
+
+    params = spec(jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg)))
+    cache = spec(jax.eval_shape(lambda: init_cache(cfg, b, c)))
+    token = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    lora, frozen = split_lora(params)
+    step = jax.jit(make_stacked_decode_step(cfg), donate_argnums=(3,))
+    with jax.default_matmul_precision("highest"):
+        compiled = step.lower(frozen, spec(lora, (4,)), token, cache, token).compile()
+    mem = compiled.memory_analysis()
+    layer_latent_bytes = b * c * (cfg.mla.kv_rank + cfg.mla.rope_dim) * 4
+    assert mem.temp_size_in_bytes < layer_latent_bytes / 2, (
+        mem.temp_size_in_bytes, layer_latent_bytes)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes, (mem.alias_size_in_bytes, cache_bytes)
+    assert "ragged-dot" in compiled.as_text()

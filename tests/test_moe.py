@@ -22,7 +22,7 @@ def test_moe_shapes_and_finite():
     cfg = _cfg()
     params = moe_init(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
-    out, aux = moe_apply(params, x, cfg)
+    out, aux, _, _ = moe_apply(params, x, cfg)
     assert out.shape == x.shape
     assert bool(jnp.all(jnp.isfinite(out)))
     assert float(aux) > 0.0
@@ -33,7 +33,7 @@ def test_aux_loss_near_one_for_balanced_router():
     cfg = _cfg(experts=8, top_k=1)
     params = moe_init(jax.random.PRNGKey(2), cfg)
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 256, 32))
-    _, aux = moe_apply(params, x, cfg)
+    _, aux, _, _ = moe_apply(params, x, cfg)
     assert 0.8 < float(aux) < 2.0  # heavily imbalanced would be >> E/2
 
 
@@ -44,8 +44,8 @@ def test_tiny_capacity_drops_tokens():
     cfg_tiny = _cfg(cf=1e-6)
     params = moe_init(jax.random.PRNGKey(4), cfg_full)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 512, 32))
-    out_full, _ = moe_apply(params, x, cfg_full)
-    out_tiny, _ = moe_apply(params, x, cfg_tiny)
+    out_full, _, _, _ = moe_apply(params, x, cfg_full)
+    out_tiny, _, _, _ = moe_apply(params, x, cfg_tiny)
     assert float(jnp.mean(jnp.abs(out_tiny))) < float(jnp.mean(jnp.abs(out_full)))
 
 
@@ -55,7 +55,7 @@ def test_moe_differentiable():
     x = jax.random.normal(jax.random.PRNGKey(7), (1, 32, 32))
 
     def loss(p):
-        out, aux = moe_apply(p, x, cfg)
+        out, aux, _, _ = moe_apply(p, x, cfg)
         return jnp.sum(out**2) + aux
 
     grads = jax.grad(loss)(params)
@@ -72,5 +72,5 @@ def test_capacity_is_per_group():
     params = moe_init(jax.random.PRNGKey(8), cfg)
     t = GROUP_SIZE * 2
     x = jax.random.normal(jax.random.PRNGKey(9), (1, t, 32))
-    out, _ = moe_apply(params, x, cfg)
+    out, _, _, _ = moe_apply(params, x, cfg)
     assert out.shape == (1, t, 32)
