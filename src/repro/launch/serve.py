@@ -132,7 +132,7 @@ def main(argv=None) -> int:
     if adapters is not None:
         print(f"[serve] adapter cache: {s['adapter_cache']} "
               f"(slots={s['adapter_slots']})")
-    print(f"[serve] decode executables: {s['executables']}")
+    print(f"[serve] executables: {s['executables']}")
     print("[serve] sample:", gen[0, :16].tolist())
     return 0
 

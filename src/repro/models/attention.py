@@ -56,10 +56,14 @@ class KVCache(NamedTuple):
 
 
 class KVRow(NamedTuple):
-    """One decode step's new K/V entry of one layer."""
+    """One decode step's new K/V entry of one layer, or with a trailing
+    slot axis T a prefill chunk's T entries."""
 
-    k: jax.Array  # (B, Kv, Dh) — RoPE already applied
-    v: jax.Array  # (B, Kv, Dh)
+    k: jax.Array  # (B, Kv, Dh), or (B, Kv, Dh, T) — RoPE already applied
+    v: jax.Array  # (B, Kv, Dh), or (B, Kv, Dh, T)
+    # a chunk's absolute position of each row, -1 for a pad row (set by the
+    # layer loop, which knows the pads); None for a decode step's one row
+    pos: jax.Array | None = None
 
 
 class LatentCache(NamedTuple):
@@ -75,18 +79,23 @@ class LatentCache(NamedTuple):
 
 
 class LatentRow(NamedTuple):
-    """One decode step's new latent row of one MLA layer."""
+    """One decode step's new latent row of one MLA layer, or with a
+    trailing slot axis T a prefill chunk's T rows."""
 
-    latent: jax.Array  # (B, kv_rank + rope_dim)
+    latent: jax.Array  # (B, kv_rank + rope_dim), or (B, kv_rank + rope_dim, T)
+    pos: jax.Array | None = None  # as ``KVRow.pos``
 
 
-def empty_rows(cache: KVCache | LatentCache):
+def empty_rows(cache: KVCache | LatentCache, rows: int | None = None):
     """Zero rows of a layer-stacked cache's row type: one per layer and
-    request, the cache's shape without its last (slot) axis."""
+    request, the cache's shape without its last (slot) axis, or with
+    ``rows`` in its place (a prefill chunk's)."""
+    def zeros(a):
+        return jnp.zeros(a.shape[:-1] + ((rows,) if rows else ()), a.dtype)
+
     if isinstance(cache, LatentCache):
-        return LatentRow(latent=jnp.zeros(cache.latent.shape[:-1], cache.latent.dtype))
-    return KVRow(k=jnp.zeros(cache.k.shape[:-1], cache.k.dtype),
-                 v=jnp.zeros(cache.v.shape[:-1], cache.v.dtype))
+        return LatentRow(latent=zeros(cache.latent))
+    return KVRow(k=zeros(cache.k), v=zeros(cache.v))
 
 
 def attn_init(rng: jax.Array, cfg: ModelConfig, *, cross: bool = False) -> dict:
@@ -242,6 +251,33 @@ def _chunked_attention(q, k, v, cfg, positions, window, causal) -> jax.Array:
     return out.transpose(1, 0, 2, 3).reshape(b, s, hq * dh)
 
 
+def _chunk_attention(q, keys, values, keys_new, values_new, pos, scale):
+    """A prefill chunk's attention over a cache and over its own rows.
+
+    ``q (B, ..., T, Dq)``, its leading axes after B those of the cache's
+    reads: ``keys (B, ..., Dq, C)`` and ``values (B, ..., Dv, C)``, read in
+    place (the decode layout, slots minor), and the chunk's own
+    ``keys_new (B, ..., Dq, T)``, ``values_new (B, ..., Dv, T)``; a GQA
+    group axis of q broadcasts over one K/V head.  Row i attends over the
+    cache's filled slots (``pos >= 0``, all before the chunk) and over the
+    chunk's rows ``<= i``, in one softmax.  Returns ``(B, ..., T, Dv)``
+    float32."""
+    f32 = jnp.float32
+    t = q.shape[-2]
+    lead = "kg" if q.ndim == 5 else "h"  # GQA (Kv, G) or MLA heads
+    kv = lead[0] if q.ndim == 5 else ""  # the cache reads' head axis
+    q = q.astype(f32)
+    scores = jnp.einsum(f"b{lead}td,b{kv}dc->b{lead}tc", q, keys.astype(f32)) * scale
+    scores = jnp.where(pos >= 0, scores, _NEG_INF)
+    own = jnp.einsum(f"b{lead}td,b{kv}ds->b{lead}ts", q, keys_new.astype(f32)) * scale
+    own = jnp.where(jnp.tril(jnp.ones((t, t), bool)), own, _NEG_INF)
+    top = jnp.maximum(jnp.max(scores, axis=-1), jnp.max(own, axis=-1))[..., None]
+    e, e_own = jnp.exp(scores - top), jnp.exp(own - top)
+    out = jnp.einsum(f"b{lead}tc,b{kv}vc->b{lead}tv", e, values.astype(f32))
+    out += jnp.einsum(f"b{lead}ts,b{kv}vs->b{lead}tv", e_own, values_new.astype(f32))
+    return out / (jnp.sum(e, axis=-1) + jnp.sum(e_own, axis=-1))[..., None]
+
+
 def attn_apply(
     params: dict,
     x: jax.Array,
@@ -263,6 +299,12 @@ def attn_apply(
     slot ``length % C``, whose old entry it is about to overwrite.  The
     cache is not written here: the new K/V comes back as a ``KVRow``, and
     ``write_kv_rows`` writes every layer's row at once.
+
+    Prefill-chunk mode (cache given, x (B, T, D) with T > 1): T new rows at
+    slots ``[length, length + T)``, which the cache must hold without
+    wrapping (no window).  Each row attends over the cache's filled slots
+    and, causally, over the chunk's rows before it (``_chunk_attention``);
+    the T new entries come back as one ``KVRow`` with a trailing slot axis.
     """
     q_flat, h_q = _project(params, x, "q", cfg, lora)
     k_flat, _ = _project(params, x, "k", cfg, lora)
@@ -295,6 +337,16 @@ def attn_apply(
             out = _dense_attention(q, k, v, cfg, positions, window, causal)
         y = dense_apply(params["wo"], out.astype(x.dtype), compute_dtype=cfg.compute_dtype)
         return y, None, lora_h
+
+    if s > 1:  # ---- prefill chunk: s new rows against one layer's cache ----
+        kv, g = cfg.num_kv_heads, cfg.q_per_kv
+        qg = q.reshape(b, s, kv, g, hd).transpose(0, 2, 3, 1, 4)  # (B, Kv, G, T, Dh)
+        k_new = k.transpose(0, 2, 3, 1).astype(cache.k.dtype)  # (B, Kv, Dh, T)
+        v_new = v.transpose(0, 2, 3, 1).astype(cache.v.dtype)
+        out = _chunk_attention(qg, cache.k, cache.v, k_new, v_new, cache.pos, hd**-0.5)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, kv * g * hd).astype(x.dtype)
+        y = dense_apply(params["wo"], out, compute_dtype=cfg.compute_dtype)
+        return y, KVRow(k=k_new, v=v_new), lora_h
 
     # ---- decode path: one new token against one layer's cache ----
     assert s == 1, "decode mode expects one new token"
@@ -333,19 +385,30 @@ def write_kv_rows(cache: KVCache | LatentCache, rows: KVRow | LatentRow):
     latent (R, B, kv_rank + rope_dim, C)); ``rows`` are the same without
     C.  Every decode step writes every layer, so all layers share one
     length and one slot.
+
+    A prefill chunk's rows (``rows.pos`` set) carry T slots in place of C
+    and go to slots ``[length, length + T)`` with one T-slot update per
+    tensor; a pad row's slot keeps ``pos`` -1, and the length advances by
+    the real rows only.
     """
     length = cache.length[0]
     slot = (length % cache.pos.shape[-1]).astype(jnp.int32)
     r = cache.pos.shape[0]
+    chunk = rows.pos is not None
     written = {
         f: jax.lax.dynamic_update_slice(
-            getattr(cache, f), row[..., None], (0,) * row.ndim + (slot,))
-        for f, row in rows._asdict().items()
+            getattr(cache, f), row if chunk else row[..., None],
+            (0,) * (row.ndim - chunk) + (slot,))
+        for f, row in rows._asdict().items() if f != "pos"
     }
+    if chunk:
+        pos, count = rows.pos, jnp.sum(rows.pos[0] >= 0, dtype=jnp.int32)
+    else:
+        pos, count = jnp.full((r, 1), length, jnp.int32), 1
     return cache._replace(
         **written,
-        pos=jax.lax.dynamic_update_slice(cache.pos, jnp.full((r, 1), length, jnp.int32), (0, slot)),
-        length=cache.length + 1,
+        pos=jax.lax.dynamic_update_slice(cache.pos, pos, (0, slot)),
+        length=cache.length + count,
     )
 
 
@@ -425,6 +488,21 @@ def _lora_per_head(lora_p: dict, o: jax.Array, *, heads: int, alpha: float, rank
     return jnp.einsum("bhr,rhv->bhv", h, bm) * (alpha / rank)
 
 
+def _latent_reads(cache: LatentCache, kv_rank: int):
+    """One layer's latent cache read where it lies: all ``kv_rank +
+    rope_dim`` values of each slot (for the scores) and the latent's first
+    ``kv_rank`` (for the weighted sum), each sliced from the layer stack on
+    its own."""
+    if cache.layer is None:
+        return cache.latent, cache.latent[:, :kv_rank]
+    stack = cache.latent
+    latent = jax.lax.dynamic_index_in_dim(stack, cache.layer, 0, keepdims=False)
+    values = jax.lax.dynamic_slice(
+        stack, (cache.layer, 0, 0, 0), (1,) + stack.shape[1:2] + (kv_rank,)
+        + stack.shape[3:])[0]
+    return latent, values
+
+
 def mla_apply(
     params: dict,
     x: jax.Array,
@@ -436,10 +514,12 @@ def mla_apply(
     lora: dict | None = None,
     causal: bool = True,
 ) -> tuple[jax.Array, LatentRow | None, jax.Array | None]:
-    """Multi-head latent attention.  Returns (output, new latent row or
-    None, lora_h), as ``attn_apply``.  LoRA ``q`` adapts ``wq``; ``v``
-    adapts the value half of kv_b_proj, ``c·(W_uv + (alpha/r)·A·B)``,
-    which is linear in the latent and so exact in the absorbed form."""
+    """Multi-head latent attention.  Returns (output, new latent row(s) or
+    None, lora_h), as ``attn_apply``, whose decode and prefill-chunk modes
+    it has, both in the absorbed form over the latent cache.  LoRA ``q``
+    adapts ``wq``; ``v`` adapts the value half of kv_b_proj,
+    ``c·(W_uv + (alpha/r)·A·B)``, which is linear in the latent and so
+    exact in the absorbed form."""
     m, heads = cfg.mla, cfg.num_heads
     cd = cfg.compute_dtype
     b, s, _ = x.shape
@@ -469,6 +549,27 @@ def mla_apply(
         y = dense_apply(params["wo"], out.astype(x.dtype), compute_dtype=cd)
         return y, None, h_q
 
+    if s > 1:  # ---- prefill chunk, absorbed: s new rows against the latent cache ----
+        f32 = jnp.float32
+        w_uk = params["wk_b"]["w"].reshape(m.kv_rank, heads, m.nope_dim)
+        q_lat = jnp.einsum("bshn,chn->bhsc", q_nope.astype(f32), w_uk.astype(f32))
+        q_all = jnp.concatenate([q_lat, q_pe.transpose(0, 2, 1, 3).astype(f32)], axis=-1)
+        rows = jnp.concatenate([c, k_pe], axis=-1).transpose(0, 2, 1).astype(cache.latent.dtype)
+        latent, values = _latent_reads(cache, m.kv_rank)
+        o_lat = _chunk_attention(q_all, latent, values, rows, rows[:, :m.kv_rank], cache.pos,
+                                 scale).transpose(0, 2, 1, 3)  # (B, T, H, kv_rank)
+        w_uv = params["wv_b"]["w"].reshape(m.kv_rank, heads, m.v_dim)
+        out = jnp.einsum("bshc,chv->bshv", o_lat.astype(jnp.dtype(cd)),
+                         w_uv.astype(jnp.dtype(cd)))
+        if v_lora is not None:
+            out = out + jax.vmap(
+                lambda o: _lora_per_head(v_lora, o, heads=heads, alpha=cfg.lora.alpha,
+                                         rank=cfg.lora.rank, compute_dtype=cd),
+                in_axes=1, out_axes=1)(o_lat)
+        out = out.reshape(b, s, heads * m.v_dim).astype(x.dtype)
+        y = dense_apply(params["wo"], out, compute_dtype=cd)
+        return y, LatentRow(latent=rows), h_q
+
     # ---- decode, absorbed: one new token against one layer's latent cache ----
     assert s == 1, "decode mode expects one new token"
     f32 = jnp.float32
@@ -476,16 +577,7 @@ def mla_apply(
     q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(f32), w_uk.astype(f32))
     q_all = jnp.concatenate([q_lat, q_pe[:, 0].astype(f32)], axis=-1)  # (B, H, 576)
     row = jnp.concatenate([c[:, 0], k_pe[:, 0]], axis=-1).astype(cache.latent.dtype)
-    # the cache is read where it lies: scores over all 576 values of a
-    # slot, the weighted sum over the latent's 512
-    if cache.layer is None:
-        latent, values = cache.latent, cache.latent[:, :m.kv_rank]
-    else:
-        stack = cache.latent
-        latent = jax.lax.dynamic_index_in_dim(stack, cache.layer, 0, keepdims=False)
-        values = jax.lax.dynamic_slice(
-            stack, (cache.layer, 0, 0, 0), (1,) + stack.shape[1:2] + (m.kv_rank,)
-            + stack.shape[3:])[0]
+    latent, values = _latent_reads(cache, m.kv_rank)
     scores = jnp.einsum("bhc,bct->bht", q_all, latent.astype(f32)) * scale
     score_new = jnp.einsum("bhc,bc->bh", q_all, row.astype(f32)) * scale
     slot = cache.length % cache.pos.shape[0]

@@ -6,6 +6,7 @@ API (all pure functions of (params, inputs)):
   forward(params, cfg, batch)                     -> (logits, Aux)
   init_cache(cfg, batch, cache_len, window=None)  -> cache pytree
   decode_step(params, cfg, cache, token)          -> (logits, cache)
+  prefill_chunk(params, cfg, cache, tokens, count) -> (logits, cache)
   prefill(params, cfg, batch, cache_len)          -> (logits, cache)
 
 ``batch``:
@@ -33,7 +34,8 @@ from repro.models.transformer import (
     stack_init,
 )
 
-__all__ = ["Aux", "init", "forward", "init_cache", "decode_step", "prefill", "input_token_len"]
+__all__ = ["Aux", "init", "forward", "init_cache", "decode_step", "prefill", "prefill_chunk",
+           "chunked_prefill_ok", "input_token_len"]
 
 
 class Aux(NamedTuple):
@@ -335,6 +337,64 @@ def decode_step(
 
     new_cache["length"] = length + 1
     return logits, new_cache
+
+
+def chunked_prefill_ok(cfg: ModelConfig, window: int | None = None) -> bool:
+    """Whether ``prefill_chunk`` can fill this model's decode cache: every
+    mixer layer is attention (a K/V or latent cache), there is no encoder
+    and no sliding-window ring."""
+    window = window if window is not None else cfg.sliding_window
+    return (cfg.encoder_layers == 0 and window is None
+            and all(cfg.is_attention_layer(j) for j in range(cfg.num_layers)))
+
+
+def prefill_chunk(
+    params: dict,
+    cfg: ModelConfig,
+    cache: dict,
+    tokens: jax.Array,  # (B, T) int32: the chunk, its pad rows after its real ones
+    count: jax.Array,  # () int32: the chunk's real rows, 1 to T
+) -> tuple[jax.Array, dict]:
+    """Prefill a chunk of T prompt tokens per request into the decode cache
+    at positions ``[length, length + T)``; returns the next-token logits
+    (B, V) after the chunk's last real row, and the cache.
+
+    Every layer attends over the cache as it stands and causally over the
+    chunk (``attn_apply``/``mla_apply`` in chunk mode), and the layers' new
+    rows are written after the layer loop, one T-slot update per tensor
+    (``write_kv_rows``).  Pad rows (``count`` to T) repeat the last real
+    row's position, are seen by no real row, route to no expert, leave
+    their slots' ``pos`` at -1 and do not advance ``length``.  Each MoE
+    layer's routes of the real rows go into the cache's record of every
+    slot, its counters into the session's sums.  The cache must hold the
+    chunk without wrapping (``chunked_prefill_ok``, ``length + T <= C``);
+    only the LM head's one row per request is computed."""
+    t = tokens.shape[1]
+    length = cache["length"]
+    rows = jnp.arange(t, dtype=jnp.int32)
+    real = rows < count
+    pos = length + jnp.minimum(rows, count - 1)
+    x = _embed_tokens(params, cfg, tokens, pos)
+
+    new_cache = dict(cache)
+    for name, c, n in _stacks(cfg):
+        st, new_cache[_cache_key(name)] = stack_apply(
+            params[name], x, c, n, positions=pos, caches=cache[_cache_key(name)], real=real)
+        x = st.x
+    if st.moe_stats is not None:
+        moe = {}
+        for n, s in st.moe_stats.items():
+            record = cache["moe"][n]["experts"]
+            at = (0, 0, 0, length % record.shape[-1])
+            kept = jax.lax.dynamic_slice(record, at, s["experts"].shape)
+            moe[n] = {"counts": cache["moe"][n]["counts"] + s["counts"],
+                      "experts": jax.lax.dynamic_update_slice(
+                          record, jnp.where(real, s["experts"], kept), at)}
+        new_cache["moe"] = moe
+    last = jax.lax.dynamic_slice_in_dim(x, count - 1, 1, axis=1)
+    h = norm_apply(params["final_norm"], last, kind=cfg.norm, eps=cfg.norm_eps)
+    new_cache["length"] = length + count
+    return _lm_logits(params, cfg, h)[:, 0], new_cache
 
 
 def prefill(

@@ -31,13 +31,13 @@ gates are normalised over the k chosen, held or not) and returns the held
 experts' part of the result plus the shared experts; the exchange with the
 chips that hold the rest is not this layer's.
 
-A decode step (one token per request) routes **dropless**: the pairs
-routed to held experts are sorted by expert and run as grouped products
-(``jax.lax.ragged_dot``) over rows enough for all of them, so no pair is
-ever dropped — a dropped token would be a different answer.  Every call
-also returns counters ``(3,) int32`` (pairs routed to held experts, held
-experts that received any, pairs dropped) and the experts each token was
-routed to.
+A decode step (one token per request), and a prefill chunk, route
+**dropless**: the pairs routed to held experts are sorted by expert and run
+as grouped products (``jax.lax.ragged_dot``) over rows enough for all of
+them, so no pair is ever dropped — a dropped token would be a different
+answer.  Every call also returns counters ``(3,) int32`` (pairs routed to
+held experts, held experts that received any, pairs dropped) and the
+experts each token was routed to.
 """
 
 from __future__ import annotations
@@ -112,9 +112,12 @@ def _experts(params: dict, x: jax.Array, cfg: ModelConfig, dot) -> jax.Array:
     return dot(hidden, params["down"])
 
 
-def _dropless(params: dict, tokens: jax.Array, gates, experts, cfg: ModelConfig):
+def _dropless(params: dict, tokens: jax.Array, gates, experts, cfg: ModelConfig,
+              real: jax.Array | None = None):
     """Every (token, held expert) pair, sorted by expert and run as grouped
     products; ``tokens (T, D)`` -> (held experts' part (T, D), counters).
+    With ``real (T,) bool`` the other tokens (a prefill chunk's pads) route
+    nowhere: they form no pair and count nowhere.
 
     The products run over twice the rows an even routing sends to the held
     experts (``budget``), or, in a step that routes more pairs there, over
@@ -124,7 +127,10 @@ def _dropless(params: dict, tokens: jax.Array, gates, experts, cfg: ModelConfig)
     count, first = moe.held
     t, k = experts.shape
     local = experts.reshape(-1) - first
-    key = jnp.where((local >= 0) & (local < count), local, count)  # count: not held
+    held = (local >= 0) & (local < count)
+    if real is not None:
+        held &= jnp.repeat(real, k)
+    key = jnp.where(held, local, count)  # count: not held
     sizes = jnp.bincount(key, length=count + 1)[:count]
     routed = jnp.sum(sizes)
     order = jnp.argsort(key, stable=True)
@@ -191,21 +197,24 @@ def _capacity(params: dict, tokens: jax.Array, gates, experts, cfg: ModelConfig)
 
 
 def moe_apply(
-    params: dict, x: jax.Array, cfg: ModelConfig
+    params: dict, x: jax.Array, cfg: ModelConfig, real: jax.Array | None = None
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """x: (B, S, D) -> (output (B,S,D), aux load-balance loss scalar,
     counters (3,) int32, experts chosen (B,S,k) int32).  One token per
-    request (S == 1, a decode step) routes dropless; a full sequence
-    dispatches with a capacity."""
+    request (S == 1, a decode step) routes dropless, and so does a prefill
+    chunk (``real (S,) bool``: its real rows; pad rows route nowhere); a
+    full sequence dispatches with a capacity."""
     assert cfg.moe is not None
     moe = cfg.moe
     cd = jnp.dtype(cfg.compute_dtype)
     b, s, d = x.shape
     t = b * s
-    if s == 1:
+    if s == 1 or real is not None:
         tokens = x.reshape(t, d).astype(cd)
         gates, experts, probs = route(params, tokens, moe, cfg.compute_dtype)
-        out, counters = _dropless(params, tokens, gates, experts, cfg)
+        if real is not None:
+            real = jnp.broadcast_to(real, (b, s)).reshape(t)
+        out, counters = _dropless(params, tokens, gates, experts, cfg, real)
     else:
         tg = min(GROUP_SIZE, t)
         assert t % tg == 0, f"token count {t} not divisible by group size {tg}"

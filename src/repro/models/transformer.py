@@ -170,9 +170,11 @@ def _apply_one(
     cache: Any | None,
     enc_out: jax.Array | None,
     causal: bool,
+    real: jax.Array | None = None,
 ) -> tuple[StackState, Any | None, jax.Array | None]:
-    """One layer: (state, new cache row or SSM cache, MoE stats or None:
-    counters and the experts each request's last token went to)."""
+    """One layer: (state, new cache row(s) or SSM cache, MoE stats or None:
+    counters and the experts each request's last token went to, or with
+    ``real`` (a prefill chunk's real rows) each of its rows', (B, k, T))."""
     mixer, mlp = _layer_kinds(cfg, j)
     x = state.x
     moe_aux = state.moe_aux
@@ -202,8 +204,9 @@ def _apply_one(
         h2 = norm_apply(params["norm2"], x, kind=cfg.norm, eps=cfg.norm_eps)
         if mlp == "moe":
             with jax.named_scope("moe"):
-                y2, aux, counts, experts = moe_apply(params["mlp"], h2, cfg)
-            stats = {"counts": counts, "experts": experts[:, -1]}
+                y2, aux, counts, experts = moe_apply(params["mlp"], h2, cfg, real)
+            stats = {"counts": counts,
+                     "experts": experts[:, -1] if real is None else experts.transpose(0, 2, 1)}
             moe_aux = moe_aux + aux
         else:
             y2 = mlp_apply(params["mlp"], h2, activation=cfg.activation, compute_dtype=cfg.compute_dtype)
@@ -254,8 +257,14 @@ def stack_apply(
     caches: dict | None = None,
     enc_out: jax.Array | None = None,
     causal: bool = True,
+    real: jax.Array | None = None,
 ) -> tuple[StackState, dict | None]:
-    """Run the full stack.  Returns (final state, updated caches or None)."""
+    """Run the full stack.  Returns (final state, updated caches or None).
+
+    With ``caches``, x is one decode step's token per request (B, 1, D), or
+    with ``real (T,) bool`` (which rows are real, the rest pad) a prefill
+    chunk's T rows (B, T, D) at positions ``positions`` that the attention
+    caches hold without wrapping; every mixer must then be attention."""
     p, repeats = _repeats(cfg, num_layers)
 
     lora_h0 = None
@@ -275,7 +284,7 @@ def stack_apply(
                 return _apply_one(
                     params_j, state, cfg, j,
                     positions=positions, window=window, cache=cache_j,
-                    enc_out=enc_out, causal=causal,
+                    enc_out=enc_out, causal=causal, real=real,
                 )
 
             if cfg.remat and p > 1:
@@ -315,8 +324,9 @@ def stack_apply(
     # (or latent) cache in place (a loop invariant, never copied out or
     # written back) and hand back their new row, which the carry gathers
     # into an (R, ...) stack of rows; after the loop one update per tensor
-    # writes every layer's row into its slot (``write_kv_rows``).  MoE
-    # layers' counters are gathered the same way.  SSM caches are
+    # writes every layer's row into its slot (``write_kv_rows``); a prefill
+    # chunk carries T rows per layer in place of one.  MoE layers'
+    # counters are gathered the same way.  SSM caches are
     # rewritten whole by their nature: they ride in the carry and each
     # layer's state is replaced in place.  While-loop carries alias; a scan
     # emitting new caches as ys holds BOTH the old stack (xs) and the new
@@ -326,10 +336,13 @@ def stack_apply(
         return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False), tree)
 
     is_kv = {n: isinstance(c, (KVCache, LatentCache)) for n, c in caches.items()}
-    carried0 = {  # per position: every repeat's new row, or the SSM cache
-        n: empty_rows(c) if is_kv[n] else c for n, c in caches.items()
+    # a prefill chunk's rows: T per layer and request, in place of one
+    chunk = x.shape[1] if real is not None else None
+    assert chunk is None or all(is_kv.values()), "a prefill chunk needs attention caches"
+    carried0 = {  # per position: every repeat's new row(s), or the SSM cache
+        n: empty_rows(c, chunk) if is_kv[n] else c for n, c in caches.items()
     }
-    counts0 = init_moe_stats(cfg, num_layers, x.shape[0]) if cfg.moe else {}
+    counts0 = init_moe_stats(cfg, num_layers, x.shape[0], chunk) if cfg.moe else {}
 
     def layer_cache(c, r):
         """Layer r's cache; a latent stack stays whole (``LatentCache.layer``)."""
@@ -352,6 +365,9 @@ def stack_apply(
     else:
         carry = jax.lax.fori_loop(0, repeats, body_carry, carry)
     final, carried, counts = carry
+    if chunk:  # each row's position, -1 for a pad
+        pos = jnp.broadcast_to(jnp.where(real, positions, -1), (repeats, chunk))
+        carried = {n: rows._replace(pos=pos) for n, rows in carried.items()}
     new_caches = {
         n: write_kv_rows(caches[n], carried[n]) if is_kv[n] else carried[n] for n in caches
     }

@@ -15,16 +15,27 @@ and ``launch/dryrun.py`` (kept importable via shims):
 
 Each layer boundary is a ``jax.profiler.TraceAnnotation`` span, recorded
 only while a profiler session is active: ``serve.attach``, ``serve.reset``,
-``serve.prefill`` and ``serve.step`` here, ``serve.page_in`` in the cache.
-Every span of one batch carries ``batch=<n>``, the count of ``attach``
-calls so far.
+``serve.prefill``, ``serve.prefill_chunk`` (tags ``pos``, the chunk's
+start, and ``valid``, its real rows) and ``serve.step`` here,
+``serve.page_in`` in the cache.  Every span of one batch carries
+``batch=<n>``, the count of ``attach`` calls so far.
 
-Compilation contract: a session compiles at most TWO decode executables —
-the single-adapter step (detached mode) and the stacked multi-tenant step
-(attached mode).  Tenant mix, slot assignment, and token values are all
-traced data; prefill teacher-forces the prompt through the SAME decode
-executable, so serving any number of tenants costs one compile.  Both
-steps donate the decode cache (in-place ring-buffer update).
+Prefill runs a ``(B, L)`` prompt as ``ceil(L / T)`` calls of one chunk
+executable, T prompt tokens per request each (``chunk_len``: the batch
+sets T), the last chunk padded; each fills the decode cache and returns
+the logits after its last real token.  Where the model cannot be prefilled
+so (``repro.models.model.chunked_prefill_ok``: a layer that is not
+attention, an encoder, a sliding-window ring) or the padded prompt does not
+fit the cache, the prompt is teacher-forced through the decode step, one
+call per token.
+
+Compilation contract: a session compiles at most one decode executable and
+one prefill-chunk executable per mode — single-adapter (detached) and
+stacked multi-tenant (attached), four in all.  Tenant mix, slot
+assignment, token values, prompt length and a chunk's real rows are all
+traced data, so serving any number of tenants and prompt lengths costs one
+compile of each.  Every executable donates the decode cache (updated in
+place).
 """
 
 from __future__ import annotations
@@ -42,11 +53,25 @@ from repro.configs.base import ModelConfig
 from repro.lora import split_lora
 from repro.models import init_cache
 from repro.models.frontends import synth_frontend_embeddings
-from repro.models.model import _run_encoder
+from repro.models.model import _run_encoder, chunked_prefill_ok
 from repro.serve.cache import AdapterCache
-from repro.serve.steps import make_decode_step, make_stacked_decode_step
+from repro.serve.steps import (
+    make_decode_step,
+    make_prefill_chunk,
+    make_stacked_decode_step,
+    make_stacked_prefill_chunk,
+)
 
-__all__ = ["ServeConfig", "ServeSession"]
+__all__ = ["ServeConfig", "ServeSession", "chunk_len"]
+
+
+def chunk_len(batch: int) -> int:
+    """Prompt tokens per request in one prefill chunk: the largest power of
+    two that keeps a chunk's ``batch * T`` tokens within 8,192, from 16 to
+    128 (batch 8: 128; batch 128: 64).  A longer chunk pads a short prompt
+    further and holds larger attention scores; a shorter one pays a call's
+    fixed cost more often."""
+    return 1 << min(7, max(4, (8192 // batch).bit_length() - 1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +107,12 @@ class ServeSession:
         self._stacked = jax.jit(
             make_stacked_decode_step(mc, window=cfg.window), donate_argnums=(3,)
         )
+        self._single_chunk = jax.jit(make_prefill_chunk(mc), donate_argnums=(1,))
+        self._stacked_chunk = jax.jit(make_stacked_prefill_chunk(mc), donate_argnums=(3,))
+        self.chunk_len = chunk_len(cfg.batch)
+        self._chunkable = chunked_prefill_ok(mc, cfg.window)
+        self._prefill = dict.fromkeys(
+            ("prefill_chunks", "prefill_tokens", "prefill_padded", "prefill_teacher_forced"), 0)
         self._slot_idx: jax.Array | None = None  # (B,) int32 when attached
         self._cache: dict | None = None
         self._logits: jax.Array | None = None
@@ -175,17 +206,50 @@ class ServeSession:
             self._pos += 1
         return self._logits
 
+    def _chunk(self, tokens: np.ndarray, valid: int) -> jax.Array:
+        """Feed one prefill chunk, ``tokens (B, T)`` of which the first
+        ``valid`` per request are real; return the logits after them."""
+        with TraceAnnotation("serve.prefill_chunk", batch=self._batch, pos=self._pos,
+                             valid=valid):
+            tok, count = jnp.asarray(tokens, jnp.int32), np.int32(valid)
+            if self._slot_idx is not None:
+                self._logits, self._cache = self._timed(
+                    "stacked_chunk", self._stacked_chunk,
+                    self._frozen, self.adapters.slab, self._slot_idx,
+                    self._cache, tok, count,
+                )
+            else:
+                self._logits, self._cache = self._timed(
+                    "single_chunk", self._single_chunk, self.params, self._cache, tok, count
+                )
+            self._pos += valid
+        return self._logits
+
     # -- serving loops ---------------------------------------------------
     def prefill(self, prompts) -> jax.Array:
-        """Teacher-force ``prompts (B, L) int32`` through the decode step
-        (resetting the cache first); returns last-position logits (B, V).
-        Smoke-scale prefill — the production full-sequence prefill shapes
-        are proven by the dry-run (``make_prefill_step``)."""
+        """Prefill ``prompts (B, L) int32`` into a fresh decode cache;
+        returns the last position's next-token logits (B, V).  Chunked
+        (``ceil(L / chunk_len)`` calls) where the model and the cache allow
+        it, else teacher-forced through the decode step (module
+        docstring)."""
         prompts = np.asarray(prompts)
+        b, length = prompts.shape
+        t = self.chunk_len
+        padded = -(-length // t) * t
         with TraceAnnotation("serve.prefill", batch=self._batch):
             self.reset()
-            for t in range(prompts.shape[1]):
-                logits = self.step(prompts[:, t])
+            if not (self._chunkable and 0 < padded <= self.cfg.cache_len):
+                self._prefill["prefill_teacher_forced"] += length
+                for i in range(length):
+                    logits = self.step(prompts[:, i])
+                return logits
+            chunks = np.zeros((b, padded), np.int32)
+            chunks[:, :length] = prompts
+            for p in range(0, length, t):
+                logits = self._chunk(chunks[:, p:p + t], min(t, length - p))
+            self._prefill["prefill_chunks"] += padded // t
+            self._prefill["prefill_tokens"] += length
+            self._prefill["prefill_padded"] += padded - length
         return logits
 
     def decode(self, num_tokens: int, *, temperature: float | None = None):
@@ -208,10 +272,14 @@ class ServeSession:
 
     # -- stats taps ------------------------------------------------------
     def executables(self) -> dict:
-        """Compiled decode-executable count per mode (the 'one donated
-        decode step' invariant: stays at 1 per mode across tenant mixes)."""
+        """Compiled executable count of the decode step (``single``,
+        ``stacked``) and the prefill chunk (``single_chunk``,
+        ``stacked_chunk``) per mode: each stays at 1 across tenant mixes and
+        prompt lengths."""
         out = {}
-        for name, fn in (("single", self._single), ("stacked", self._stacked)):
+        for name, fn in (("single", self._single), ("stacked", self._stacked),
+                         ("single_chunk", self._single_chunk),
+                         ("stacked_chunk", self._stacked_chunk)):
             size = getattr(fn, "_cache_size", None)
             out[name] = int(size()) if callable(size) else -1
         return out
@@ -230,10 +298,14 @@ class ServeSession:
             "first_step_s": dict(self._first_s),
             "executables": self.executables(),
             "attached": self.attached,
+            # over the session: prefill chunks run, their real and pad
+            # rows, and prompt tokens teacher-forced through the decode step
+            **self._prefill,
         }
         if self._cache is not None and "moe" in self._cache:
-            # per MoE layer over the session's decode steps: pairs routed to
-            # held experts, held experts used (summed over steps), pairs dropped
+            # per MoE layer over the session's decode steps and prefill
+            # chunks: pairs routed to held experts, held experts used (summed
+            # over the calls), pairs dropped
             counts = np.concatenate([np.asarray(m["counts"]) for m in self._cache["moe"].values()])
             s["moe_routed_pairs"] = counts[:, 0].tolist()
             s["moe_experts_used"] = counts[:, 1].tolist()

@@ -2,11 +2,13 @@
 
 A tiny two-batch session runs under ``jax.profiler.trace``; its host plane
 must hold the layer boundaries ``serve.attach`` / ``serve.page_in`` /
-``serve.reset`` / ``serve.prefill`` / ``serve.step``, nested as the calls
-nest, every span of one batch tagged with the same ``batch``, one
-``serve.step`` per prompt token inside ``serve.prefill`` and one
-``serve.page_in`` per adapter-cache miss.  Only an executable's first call
-waits for the device.
+``serve.reset`` / ``serve.prefill`` / ``serve.prefill_chunk`` /
+``serve.step``, nested as the calls nest, every span of one batch tagged
+with the same ``batch``, ``ceil(L / T)`` ``serve.prefill_chunk`` spans and
+no ``serve.step`` inside each ``serve.prefill`` and one ``serve.page_in``
+per adapter-cache miss.  A session that cannot prefill in chunks (a
+sliding window) still runs one ``serve.step`` per prompt token.  Only an
+executable's first call waits for the device.
 """
 
 import glob
@@ -21,16 +23,21 @@ from repro.configs.gpt2_paper import REDUCED_CLIENT
 from repro.lora import lora_template
 from repro.models import init as model_init
 from repro.serve import AdapterCache, ServeConfig, ServeSession
+from repro.serve.session import chunk_len
 
+BATCH, SLOTS, GEN = 4, 4, 3
+T = chunk_len(BATCH)
+# (tenants, prompt length) of each batch: the second evicts and pages in,
+# and its prompt takes three chunks, the last with 3 real rows
+BATCHES = [([0, 1, 1, 2], 5), ([3, 4, 0, 5], 2 * T + 3)]
+CACHE = 3 * T + GEN
 CFG = REDUCED_CLIENT.with_overrides(
     num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, d_ff=128,
-    vocab_size=256, max_seq_len=64,
+    vocab_size=256, max_seq_len=CACHE,
     lora=LoRAConfig(rank=4, alpha=8.0, dropout=0.0, targets=("q", "v")),
 )
-BATCH, SLOTS, GEN = 4, 4, 3
-# (tenants, prompt length) of each batch: the second evicts and pages in
-BATCHES = [([0, 1, 1, 2], 5), ([3, 4, 0, 5], 7)]
-SPANS = {"serve.attach", "serve.page_in", "serve.reset", "serve.prefill", "serve.step"}
+SPANS = {"serve.attach", "serve.page_in", "serve.reset", "serve.prefill",
+         "serve.prefill_chunk", "serve.step"}
 
 
 class Rows:
@@ -43,24 +50,26 @@ class Rows:
         return jax.tree.map(lambda x: np.full(x.shape, 0.01 * cid, x.dtype), self.like)
 
 
-def _session():
+def _session(window=None):
     params = model_init(jax.random.PRNGKey(0), CFG)
     like = lora_template(params)
     cache = AdapterCache(Rows(like, 8), like=like, slots=SLOTS)
-    return ServeSession(ServeConfig(model=CFG, batch=BATCH, cache_len=16), params,
-                        adapters=cache)
+    return ServeSession(ServeConfig(model=CFG, batch=BATCH, cache_len=CACHE, window=window),
+                        params, adapters=cache)
 
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """(session, [(name, start_ns, end_ns, tags)] of the serve.* host spans)."""
+    return _trace(_session(), BATCHES, str(tmp_path_factory.mktemp("serve_trace")))
+
+
+def _trace(sess, batches, out):
     from jax.profiler import ProfileData
 
-    sess = _session()
     rng = np.random.default_rng(0)
-    out = str(tmp_path_factory.mktemp("serve_trace"))
     with jax.profiler.trace(out):
-        for tenants, length in BATCHES:
+        for tenants, length in batches:
             sess.attach(tenants)
             sess.prefill(rng.integers(0, CFG.vocab_size, (BATCH, length), dtype=np.int32))
             _, logits = sess.decode(GEN)
@@ -96,6 +105,8 @@ def test_span_names_and_nesting(traced):
     assert len(_of(spans, "serve.reset")) == 2 * len(BATCHES)
     for step in _of(spans, "serve.step"):
         assert not any(_inside(step, a) for a in attaches)
+    for chunk in _of(spans, "serve.prefill_chunk"):
+        assert any(_inside(chunk, p) for p in prefills)
 
 
 def test_one_batch_shares_its_batch_tag(traced):
@@ -109,17 +120,34 @@ def test_one_batch_shares_its_batch_tag(traced):
         assert {s[0] for s in batch} == SPANS  # each batch pages something in
 
 
-def test_prefill_runs_one_step_per_prompt_token(traced):
+def test_prefill_runs_one_chunk_per_T_prompt_tokens(traced):
     _, spans = traced
-    steps = _of(spans, "serve.step")
+    steps, chunks = _of(spans, "serve.step"), _of(spans, "serve.prefill_chunk")
     for prefill, (_, length) in zip(_of(spans, "serve.prefill"), BATCHES):
-        inner = [s for s in steps if _inside(s, prefill)]
-        assert len(inner) == length
-        assert [s[3]["pos"] for s in inner] == list(range(length))
-        # the decode steps that follow continue the positions
+        inner = [s for s in chunks if _inside(s, prefill)]
+        starts = list(range(0, length, T))
+        assert [s[3]["pos"] for s in inner] == starts
+        assert [s[3]["valid"] for s in inner] == [min(T, length - p) for p in starts]
+        assert not [s for s in steps if _inside(s, prefill)]
+        # the decode steps that follow continue at the prompt's length
         after = [s for s in steps if s[1] > prefill[2]][:GEN]
         assert [s[3]["pos"] for s in after] == list(range(length, length + GEN))
         assert {s[3]["batch"] for s in inner + after} == {prefill[3]["batch"]}
+
+
+def test_prefill_runs_one_step_per_prompt_token(tmp_path):
+    # where the model cannot be prefilled in chunks (here a sliding window)
+    sess = _session(window=8)
+    _, spans = _trace(sess, BATCHES, str(tmp_path))
+    steps = _of(spans, "serve.step")
+    assert not _of(spans, "serve.prefill_chunk")
+    for prefill, (_, length) in zip(_of(spans, "serve.prefill"), BATCHES):
+        inner = [s for s in steps if _inside(s, prefill)]
+        assert [s[3]["pos"] for s in inner] == list(range(length))
+        after = [s for s in steps if s[1] > prefill[2]][:GEN]
+        assert [s[3]["pos"] for s in after] == list(range(length, length + GEN))
+    assert sess.stats()["prefill_teacher_forced"] == sum(n for _, n in BATCHES)
+    assert sess.stats()["executables"]["stacked_chunk"] == 0
 
 
 def test_one_page_in_per_cache_miss(traced):
@@ -136,8 +164,9 @@ def test_stats_keep_no_steady_timing(traced):
     sess, _ = traced
     s = sess.stats()
     assert not {"steady_step_s", "steady_steps", "tokens_decoded"} & set(s)
-    assert set(s["first_step_s"]) == {"stacked"} and s["first_step_s"]["stacked"] > 0
-    assert s["executables"]["stacked"] == 1
+    assert set(s["first_step_s"]) == {"stacked", "stacked_chunk"}
+    assert min(s["first_step_s"].values()) > 0
+    assert s["executables"]["stacked"] == s["executables"]["stacked_chunk"] == 1
     assert {"adapter_cache", "adapter_slots", "resident_adapters"} <= set(s)
 
 
