@@ -5,7 +5,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   fig2_accuracy — paper Fig. 2 (accuracy vs rounds, 4 methods, Non-IID)
   fig3_comm     — paper Fig. 3 (MB to accuracy thresholds, IID)
   agg_ablation  — §III-A aggregation-vs-sparsity analysis
-  kernel_*      — Pallas kernel hot-spot microbenches
 
 ``python -m benchmarks.run`` runs quick variants (CI-speed); pass --full for
 the long curves that populate EXPERIMENTS.md.
@@ -28,14 +27,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
-    from benchmarks import agg_ablation, engine_bench, fig2_accuracy, fig3_comm, kernel_bench
+    from benchmarks import agg_ablation, fig2_accuracy, fig3_comm
 
     benches = {
-        "kernel": kernel_bench.bench,
-        "engine": engine_bench.bench,
-        "round": engine_bench.bench_round,
-        "hetero": engine_bench.bench_hetero,
-        "quant": engine_bench.bench_quant,
         "agg": agg_ablation.bench,
         "fig2": fig2_accuracy.bench,
         "fig3": fig3_comm.bench,

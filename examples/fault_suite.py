@@ -5,19 +5,14 @@ payload corruption with HARQ retransmission, client crashes mid-upload,
 Gilbert-Elliott fault bursts, the lossy kitchen sink) through the batched
 engine and records per-preset curves: server accuracy, per-round uplink
 bytes (retransmitted copies included), quarantine/crash counts and
-retransmission bytes.  The record is the committed ``BENCH_faults.json``
-gated by ``benchmarks/check_bench.py``.
+retransmission bytes.  It prints one line per preset and, with ``--out``,
+writes the curves as JSON.  The ``none`` preset doubles as the bit-identity
+witness: the suite re-runs with ``faults=None`` and reports whether the two
+are indistinguishable.  (The per-round realisations of the corruption,
+crashes and lossy presets are pinned by ``tests/test_faults.py``'s golden
+trajectory.)
 
-Determinism contract (what makes the gate equality-shaped): fault draws are
-keyed per ``(seed, domain, round, cid)`` and cohort draws are consumed
-round-by-round from one seeded rng, so a ``--quick`` run's rounds are a
-PREFIX of the full run's — per-round uplink bytes and quarantine counts at
-quick scale must equal the committed record's leading rounds exactly.  The
-``none`` preset doubles as the bit-identity witness: the suite re-runs with
-``faults=None`` and records whether the two are indistinguishable.
-
-Run:  PYTHONPATH=src python examples/fault_suite.py            # full record
-      PYTHONPATH=src python examples/fault_suite.py --quick    # CI gate
+Run:  PYTHONPATH=src python examples/fault_suite.py [--out curves.json]
 """
 
 import argparse
@@ -35,8 +30,6 @@ from repro.core import FAULTS, ChannelConfig  # noqa: E402
 from repro.data import make_banking77_like  # noqa: E402
 from repro.fed import FedConfig, run_federated  # noqa: E402
 
-_REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
-
 LORA = LoRAConfig(rank=4, alpha=32.0, dropout=0.0, targets=("q", "v", "head"))
 CLIENT = REDUCED_CLIENT.with_overrides(
     num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, d_ff=128,
@@ -49,8 +42,7 @@ SERVER = REDUCED_SERVER.with_overrides(
 # Constrained uplink so the adaptive k varies and HARQ retries actually
 # price against a finite Shannon budget.
 CHAN = ChannelConfig(bandwidth_hz=2e5, mean_snr_db=2.0, min_k=0, dropout_prob=0.1)
-FULL_ROUNDS = 10
-QUICK_ROUNDS = 4
+ROUNDS = 10
 
 
 def _fed(rounds: int, faults) -> FedConfig:
@@ -82,23 +74,18 @@ def run_preset(ds, rounds: int, faults):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--quick", action="store_true",
-                    help=f"{QUICK_ROUNDS} rounds instead of {FULL_ROUNDS} "
-                         "(a prefix of the full record; writes "
-                         "BENCH_faults.quick.json for the CI gate)")
-    ap.add_argument("--out", default=None, help="output JSON path override")
+    ap.add_argument("--out", default=None, help="write the curves to this JSON file")
     args = ap.parse_args(argv)
 
-    rounds = QUICK_ROUNDS if args.quick else FULL_ROUNDS
     ds = make_banking77_like(vocab_size=CLIENT.vocab_size, seq_len=12,
                             total=500, seed=0)
 
-    record = {"quick": bool(args.quick), "rounds": rounds, "presets": {}}
+    record = {"rounds": ROUNDS, "presets": {}}
     print(f"{'preset':>12} {'uplink MB':>10} {'quar':>5} {'crash':>6} "
           f"{'retrans MB':>11} {'final acc':>10}")
     runs = {}
     for name in FAULTS:
-        run, out = run_preset(ds, rounds, name)
+        run, out = run_preset(ds, ROUNDS, name)
         runs[name] = run
         record["presets"][name] = out
         quar = sum(out.get("num_quarantined", [0]))
@@ -109,7 +96,7 @@ def main(argv=None) -> int:
 
     # The disabled-machinery guarantee with teeth: the `none` preset must be
     # bit-identical to a run with NO faults configured at all.
-    baseline, base_out = run_preset(ds, rounds, None)
+    baseline, base_out = run_preset(ds, ROUNDS, None)
     none = runs["none"]
     record["no_fault_bit_identical"] = bool(
         none.per_client_k == baseline.per_client_k
@@ -119,11 +106,10 @@ def main(argv=None) -> int:
     print(f"\nnone preset vs faults=None bit-identical: "
           f"{record['no_fault_bit_identical']}")
 
-    suffix = "quick.json" if args.quick else "json"
-    path = args.out or os.path.join(_REPO_ROOT, f"BENCH_faults.{suffix}")
-    with open(path, "w") as f:
-        json.dump(record, f, indent=1)
-    print(f"wrote {path}")
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(record, f, indent=1)
+        print(f"wrote {args.out}")
     return 0
 
 

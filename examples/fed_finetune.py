@@ -14,8 +14,8 @@ single vmapped/jitted per-phase steps), ``fused`` (the entire client phase
 jitted call per round), ``fused_e2e`` (the WHOLE round — client phase plus
 sparse-wire aggregation, server distillation and broadcast — as one
 compiled call) or ``sequential`` (the bit-compatible one-client-at-a-time
-reference) — see FedConfig.engine.  All engines use the
-last-position-only LM head (FedConfig.last_only).
+reference) — see FedConfig.engine.  All engines compute the LM head on
+the last position only.
 """
 
 import os

@@ -6,17 +6,12 @@ Gauss-Markov AR(1) fading, Jakes/Doppler fading, Gilbert-Elliott bursty
 outage, mobility trajectories) through the one-dispatch ``fused_e2e``
 multi-round scan and records fig2/fig3-style curves per scenario: server
 accuracy against cumulative uplink MB, the per-round adaptive k, and the
-in-scan outage tap.  The record is the committed ``BENCH_scenario.json``
-gated by ``benchmarks/check_bench.py``.
+in-scan outage tap.  It prints one line per scenario and, with ``--out``,
+writes the curves as JSON.  (The per-round k and payload bytes of the
+correlated presets are pinned by ``tests/test_scenario.py``'s golden
+trajectory.)
 
-Determinism contract (what makes the gate equality-shaped): channel draws
-are keyed per ``(seed, round, cid)`` and cohort draws are consumed
-round-by-round from one seeded rng, so a ``--quick`` run's rounds are a
-PREFIX of the full run's — per-round uplink bytes at quick scale must equal
-the committed record's leading rounds byte-for-byte.
-
-Run:  PYTHONPATH=src python examples/scenario_suite.py            # full record
-      PYTHONPATH=src python examples/scenario_suite.py --quick    # CI gate
+Run:  PYTHONPATH=src python examples/scenario_suite.py [--out curves.json]
 """
 
 import argparse
@@ -33,8 +28,6 @@ from repro.core import SCENARIOS, ChannelConfig  # noqa: E402
 from repro.data import make_banking77_like  # noqa: E402
 from repro.fed import FedConfig, run_federated  # noqa: E402
 
-_REPO_ROOT = os.path.join(os.path.dirname(__file__), "..")
-
 CLIENT = REDUCED_CLIENT.with_overrides(
     num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, d_ff=128,
     vocab_size=256, max_seq_len=32,
@@ -46,8 +39,7 @@ SERVER = REDUCED_SERVER.with_overrides(
 # Constrained uplink so the adaptive k actually moves with the fading, plus
 # a nonzero memoryless dropout so the i.i.d. presets exercise outage too.
 CHAN = ChannelConfig(bandwidth_hz=2e5, mean_snr_db=2.0, min_k=0, dropout_prob=0.1)
-FULL_ROUNDS = 10
-QUICK_ROUNDS = 4
+ROUNDS = 10
 
 
 def _fed(rounds: int, scenario) -> FedConfig:
@@ -79,23 +71,18 @@ def run_scenario(ds, rounds: int, scenario):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--quick", action="store_true",
-                    help=f"{QUICK_ROUNDS} rounds instead of {FULL_ROUNDS} "
-                         "(a prefix of the full record; writes "
-                         "BENCH_scenario.quick.json for the CI gate)")
-    ap.add_argument("--out", default=None, help="output JSON path override")
+    ap.add_argument("--out", default=None, help="write the curves to this JSON file")
     args = ap.parse_args(argv)
 
-    rounds = QUICK_ROUNDS if args.quick else FULL_ROUNDS
     ds = make_banking77_like(vocab_size=CLIENT.vocab_size, seq_len=12,
                             total=500, seed=0)
 
-    record = {"quick": bool(args.quick), "rounds": rounds, "scenarios": {}}
+    record = {"rounds": ROUNDS, "scenarios": {}}
     print(f"{'scenario':>16} {'mean k':>8} {'uplink MB':>10} {'outage':>7} "
           f"{'best acc':>9}")
     runs = {}
     for name in SCENARIOS:
-        run, out = run_scenario(ds, rounds, name)
+        run, out = run_scenario(ds, ROUNDS, name)
         runs[name] = run
         record["scenarios"][name] = out
         print(f"{name:>16} {np.mean(out['mean_k']):8.0f} "
@@ -104,7 +91,7 @@ def main(argv=None) -> int:
 
     # The rho=0 guarantee with teeth: the `iid` preset must be bit-identical
     # to a run with NO scenario at all (the legacy per-round i.i.d. path).
-    legacy, legacy_out = run_scenario(ds, rounds, None)
+    legacy, legacy_out = run_scenario(ds, ROUNDS, None)
     iid = runs["iid"]
     record["iid_bit_identical"] = bool(
         iid.per_client_k == legacy.per_client_k
@@ -114,11 +101,10 @@ def main(argv=None) -> int:
     print(f"\niid preset vs legacy i.i.d. path bit-identical: "
           f"{record['iid_bit_identical']}")
 
-    suffix = "quick.json" if args.quick else "json"
-    path = args.out or os.path.join(_REPO_ROOT, f"BENCH_scenario.{suffix}")
-    with open(path, "w") as f:
-        json.dump(record, f, indent=1)
-    print(f"wrote {path}")
+    if args.out:
+        with open(args.out, "w") as f:
+            json.dump(record, f, indent=1)
+        print(f"wrote {args.out}")
     return 0
 
 

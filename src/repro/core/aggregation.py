@@ -277,12 +277,11 @@ def max_intermediate_elems(jaxpr) -> int:
     """Largest element count of any equation output anywhere in a jaxpr —
     sub-jaxprs (pjit / scan / cond bodies) included.
 
-    This is the inspection behind the sparse path's memory contract: the
-    whole-round benchmark and ``tests/test_engine.py`` both assert that
+    This is the inspection behind the sparse path's memory contract:
+    ``tests/test_engine.py`` asserts that
     ``max_intermediate_elems(jax.make_jaxpr(aggregate_wire-ish)(...))``
-    stays below the dense ``(N, B, V)`` stack's element count (ONE shared
-    implementation, so the committed BENCH_round.json proof and the CI test
-    can never diverge).  Accepts a ``ClosedJaxpr`` or a raw ``Jaxpr``.
+    stays below the dense ``(N, B, V)`` stack's element count.  Accepts a
+    ``ClosedJaxpr`` or a raw ``Jaxpr``.
     """
     from jax import core as jax_core
 

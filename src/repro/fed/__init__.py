@@ -5,7 +5,7 @@ from repro.fed.cohort import (
     split_cohort,
     validate_family_contracts,
 )
-from repro.fed.engine import (
+from repro.fed.engines import (
     BatchedEngine,
     BroadcastState,
     ClientPhase,

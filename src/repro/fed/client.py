@@ -76,7 +76,6 @@ class Client:
         local_steps: int = 4,
         distill_steps: int = 2,
         restrict_to_support: bool = False,
-        last_only: bool = True,
         initial_params=None,
     ):
         self.client_id = client_id
@@ -86,7 +85,6 @@ class Client:
         self.batch_size = batch_size
         self.local_steps = local_steps
         self.distill_steps = distill_steps
-        self.last_only = last_only
         if initial_params is not None:
             # shared pretrained backbone W' (paper eq. 1) + fresh LoRA delta
             import jax as _jax
@@ -99,12 +97,10 @@ class Client:
         else:
             self.params = model_init(jax.random.PRNGKey(seed), cfg)
         self.opt = fed_steps.init_lora_opt(self.params, cfg)
-        self._train_step = fed_steps.make_finetune_step(
-            cfg, num_classes, lr=lr, last_only=last_only
-        )
+        self._train_step = fed_steps.make_finetune_step(cfg, num_classes, lr=lr)
         self._distill_step = fed_steps.make_distill_step(
             cfg, lr=distill_lr, temperature=temperature, lam=lam,
-            restrict_to_support=restrict_to_support, last_only=last_only,
+            restrict_to_support=restrict_to_support,
         )
         self._rng = np.random.default_rng(seed + 1000 * (client_id + 1))
 
@@ -178,9 +174,7 @@ class Client:
             )
         if k == 0:
             return None
-        logits, h = fed_steps.public_logits(
-            self.params, self.cfg, public_tokens, last_only=self.last_only
-        )
+        logits, h = fed_steps.public_logits(self.params, self.cfg, public_tokens)
         sparse = topk_sparsify(logits, k)
         payload, _ = make_upload_payload(
             self.cfg, self.client_id, n_samples, k,

@@ -79,7 +79,7 @@ def partition_fleet(clients: Sequence[Client]) -> list[FamilyBucket]:
     ``shared_backbone=False`` (stacked frozens) rather than fragmenting into
     singletons — the vmapped executable still serves it.
     """
-    from repro.fed.engine import shared_frozen_backbone
+    from repro.fed.engines.base import shared_frozen_backbone
     from repro.lora import split_lora
 
     order: list[ModelConfig] = []

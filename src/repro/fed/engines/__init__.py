@@ -50,13 +50,9 @@ from repro.fed.engines.base import (
     ClientPhase,
     RoundsTrajectory,
     SequentialEngine,
-    _channel_scan_ops,
-    _ServerOwnerMixin,
     check_unique_cohort,
     cohort_budgets,
-    fake_quant_dense,
     k_cap_bucket,
-    shared_frozen_backbone,
     tree_stack,
 )
 from repro.fed.engines.batched import BatchedEngine
@@ -80,10 +76,6 @@ __all__ = [
     "cohort_budgets",
     "check_unique_cohort",
 ]
-
-# referenced via the package for the engine.py shim's star-import era callers
-_PRIVATE_REEXPORTS = (_ServerOwnerMixin, _channel_scan_ops, fake_quant_dense,
-                      shared_frozen_backbone)
 
 
 def make_engine(kind: str, clients: list[Client], cfg: ModelConfig, **kwargs):

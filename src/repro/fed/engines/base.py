@@ -1,8 +1,7 @@
 """Shared engine plumbing: budget math, round dataclasses, the sequential
 reference engine, and the server-owner mixin.
 
-See :mod:`repro.fed.engines` for the package overview (this file is the
-PR-9 split of the former monolithic ``repro.fed.engine`` module).
+See :mod:`repro.fed.engines` for the package overview.
 """
 
 from __future__ import annotations

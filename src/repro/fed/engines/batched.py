@@ -65,8 +65,6 @@ class BatchedEngine:
         restrict_to_support: bool = False,
         value_bits: int = 16,
         k_min: int = 1,
-        last_only: bool = True,
-        class_head_only: bool = True,
         quantize_wire: bool = False,
         fleet_store: "str | FleetStore" = "device",
     ):
@@ -76,7 +74,6 @@ class BatchedEngine:
         self.distill_steps = distill_steps
         self.value_bits = value_bits
         self.k_min = k_min
-        self.last_only = last_only
         self.quantize_wire = quantize_wire
 
         loras, frozens = zip(*(split_lora(c.params) for c in clients))
@@ -86,16 +83,14 @@ class BatchedEngine:
             opts=[c.opt for c in clients], shared=self._shared,
         )
         self._train = fed_steps.make_batched_finetune_step(
-            cfg, num_classes, lr=lr, shared_backbone=self._shared, last_only=last_only,
-            class_head_only=class_head_only,
+            cfg, num_classes, lr=lr, shared_backbone=self._shared
         )
         self._distill = fed_steps.make_batched_distill_step(
             cfg, lr=distill_lr, temperature=temperature, lam=lam,
             restrict_to_support=restrict_to_support, shared_backbone=self._shared,
-            last_only=last_only,
         )
         self._public = fed_steps.make_batched_public_logits(
-            cfg, shared_backbone=self._shared, last_only=last_only
+            cfg, shared_backbone=self._shared
         )
 
     # -- fleet-state ownership: delegated to the store -------------------

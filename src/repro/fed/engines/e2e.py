@@ -84,7 +84,6 @@ class FusedE2EEngine(_ServerOwnerMixin, FusedEngine):
         restrict_to_support: bool = False,
         value_bits: int = 16,
         k_min: int = 1,
-        last_only: bool = True,
         shard_clients: bool = False,
         use_kernels: bool = False,
         quantize_wire: bool = False,
@@ -95,9 +94,9 @@ class FusedE2EEngine(_ServerOwnerMixin, FusedEngine):
             clients, cfg, num_classes=num_classes, lr=lr, distill_lr=distill_lr,
             temperature=temperature, lam=lam, local_steps=local_steps,
             distill_steps=distill_steps, restrict_to_support=restrict_to_support,
-            value_bits=value_bits, k_min=k_min, last_only=last_only,
-            use_kernels=use_kernels, quantize_wire=quantize_wire,
-            compute_dtype=compute_dtype, fleet_store=fleet_store,
+            value_bits=value_bits, k_min=k_min, use_kernels=use_kernels,
+            quantize_wire=quantize_wire, compute_dtype=compute_dtype,
+            fleet_store=fleet_store,
         )
         self.shard_clients = shard_clients
         self._fn_kwargs = dict(
@@ -106,8 +105,7 @@ class FusedE2EEngine(_ServerOwnerMixin, FusedEngine):
             distill_steps=distill_steps,
             server_distill_steps=server_distill_steps,
             aggregation=aggregation, shared_backbone=self._shared,
-            last_only=last_only, use_kernels=use_kernels,
-            shard_clients=shard_clients, quantize=quantize_wire,
+            use_kernels=use_kernels, shard_clients=shard_clients, quantize=quantize_wire,
             compute_dtype=compute_dtype,
         )
         self._num_classes = num_classes
@@ -214,12 +212,8 @@ class FusedE2EEngine(_ServerOwnerMixin, FusedEngine):
         chan_step = fed_steps.make_channel_step_fn() if has_chan else None
         # in-scan eval tap: same last-position class-logit accuracy as the
         # host-side make_eval_fn, traced into the scanned round program
-        server_eval = fed_steps.make_scan_eval_fn(
-            self.server.cfg, self._num_classes, last_only=self.last_only
-        )
-        client_eval = fed_steps.make_scan_eval_fn(
-            self.cfg, self._num_classes, last_only=self.last_only
-        )
+        server_eval = fed_steps.make_scan_eval_fn(self.server.cfg, self._num_classes)
+        client_eval = fed_steps.make_scan_eval_fn(self.cfg, self._num_classes)
 
         shared = self._shared
 

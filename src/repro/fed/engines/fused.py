@@ -61,10 +61,8 @@ class FusedEngine(BatchedEngine):
         restrict_to_support: bool = False,
         value_bits: int = 16,
         k_min: int = 1,
-        last_only: bool = True,
         shard_clients: bool = False,
         use_kernels: bool = False,
-        class_head_only: bool = True,
         quantize_wire: bool = False,
         compute_dtype: str = "float32",
         fleet_store: "str | FleetStore" = "device",
@@ -73,8 +71,7 @@ class FusedEngine(BatchedEngine):
             clients, cfg, num_classes=num_classes, lr=lr, distill_lr=distill_lr,
             temperature=temperature, lam=lam, local_steps=local_steps,
             distill_steps=distill_steps, restrict_to_support=restrict_to_support,
-            value_bits=value_bits, k_min=k_min, last_only=last_only,
-            class_head_only=class_head_only, quantize_wire=quantize_wire,
+            value_bits=value_bits, k_min=k_min, quantize_wire=quantize_wire,
             fleet_store=fleet_store,
         )
         self.shard_clients = shard_clients
@@ -86,8 +83,7 @@ class FusedEngine(BatchedEngine):
                 temperature=temperature, lam=lam,
                 restrict_to_support=restrict_to_support,
                 local_steps=local_steps, distill_steps=n_distill,
-                shared_backbone=self._shared, last_only=last_only,
-                use_kernels=use_kernels, class_head_only=class_head_only,
+                shared_backbone=self._shared, use_kernels=use_kernels,
                 compute_dtype=compute_dtype,
             )
             if shard_clients:

@@ -206,7 +206,6 @@ class HeteroFusedE2EEngine(_ServerOwnerMixin):
         restrict_to_support: bool = False,
         value_bits: int = 16,
         k_min: int = 1,
-        last_only: bool = True,
         shard_clients: bool = False,
         use_kernels: bool = False,
         quantize_wire: bool = False,
@@ -226,7 +225,6 @@ class HeteroFusedE2EEngine(_ServerOwnerMixin):
         self._where = fleet_index(self.buckets)
         self.clients = clients
         self.vocab = self.buckets[0].cfg.vocab_size
-        self.last_only = last_only
         self._num_classes = num_classes
         self._local_steps = local_steps
         self.quantize_wire = quantize_wire
@@ -235,8 +233,7 @@ class HeteroFusedE2EEngine(_ServerOwnerMixin):
             temperature=temperature, lam=lam, local_steps=local_steps,
             distill_steps=distill_steps,
             restrict_to_support=restrict_to_support, value_bits=value_bits,
-            k_min=k_min, last_only=last_only, quantize_wire=quantize_wire,
-            fleet_store=fleet_store,
+            k_min=k_min, quantize_wire=quantize_wire, fleet_store=fleet_store,
         )
         # one BatchedEngine per bucket as the stacked-fleet STATE HOLDER
         # (gather/scatter/budget/batch plumbing); its per-phase steps are
@@ -249,15 +246,14 @@ class HeteroFusedE2EEngine(_ServerOwnerMixin):
         self._phase_kwargs = dict(
             lr=lr, distill_lr=distill_lr, temperature=temperature, lam=lam,
             restrict_to_support=restrict_to_support, local_steps=local_steps,
-            distill_steps=distill_steps, last_only=last_only,
-            quantize=quantize_wire, compute_dtype=compute_dtype,
+            distill_steps=distill_steps, quantize=quantize_wire,
+            compute_dtype=compute_dtype,
         )
         self._server_kwargs = dict(
             vocab=self.vocab, distill_lr=distill_lr, temperature=temperature,
             lam=lam, restrict_to_support=restrict_to_support,
             server_distill_steps=server_distill_steps,
-            aggregation=aggregation, last_only=last_only,
-            use_kernels=use_kernels, quantize=quantize_wire,
+            aggregation=aggregation, use_kernels=use_kernels, quantize=quantize_wire,
             compute_dtype=compute_dtype,
         )
         self._init_server_state(server)
@@ -431,14 +427,9 @@ class HeteroFusedE2EEngine(_ServerOwnerMixin):
         has_h = self.server.cfg.lora is not None
         shared = [be._shared for be in self._b]
         sizes = [b.size for b in self.buckets]
-        server_eval = fed_steps.make_scan_eval_fn(
-            self.server.cfg, self._num_classes, last_only=self.last_only
-        )
+        server_eval = fed_steps.make_scan_eval_fn(self.server.cfg, self._num_classes)
         family_evals = [
-            fed_steps.make_scan_eval_fn(
-                b.cfg, self._num_classes, last_only=self.last_only
-            )
-            for b in self.buckets
+            fed_steps.make_scan_eval_fn(b.cfg, self._num_classes) for b in self.buckets
         ]
 
         def driver(fleet_loras, fleet_opts, s_lora, s_opt, frozens, s_frozen,

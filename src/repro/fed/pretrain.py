@@ -24,7 +24,7 @@ from repro.data.pipeline import epoch_batches
 from repro.data.synthetic import IntentDataset
 from repro.fed import steps as fed_steps
 from repro.lora import split_lora
-from repro.models import forward, init as model_init
+from repro.models import init as model_init
 from repro.optim import adamw_init, adamw_update
 
 __all__ = ["pretrain_classifier"]
@@ -44,15 +44,13 @@ def _owned(tree):
     return jax.tree.map(jnp.copy, tree)
 
 
-def _supervised_step(cfg: ModelConfig, num_classes: int, lr: float, last_only: bool):
+def _supervised_step(cfg: ModelConfig, num_classes: int, lr: float):
     def loss_fn(params, batch):
-        # last_only head: classification reads the final position exclusively,
-        # and only the num_classes head columns (bit-identical to slicing)
-        logits, aux = forward(
-            params, cfg, {"tokens": batch["tokens"]}, last_only=last_only,
-            head_cols=num_classes if last_only else None,
+        # classification reads the final position exclusively, and only the
+        # num_classes head columns (bit-identical to slicing)
+        last, aux = fed_steps.last_logits(
+            params, cfg, {"tokens": batch["tokens"]}, head_cols=num_classes
         )
-        last = logits if last_only else logits[:, -1, :]
         cls = fed_steps.class_logits(last, num_classes)
         logp = jax.nn.log_softmax(cls.astype(jnp.float32), axis=-1)
         nll = -jnp.take_along_axis(logp, batch["labels"][:, None], axis=-1).mean()
@@ -77,19 +75,18 @@ def pretrain_classifier(
     lr: float = 2e-3,
     batch_size: int = 64,
     seed: int = 0,
-    last_only: bool = True,
     verbose: bool = False,
 ):
     """Full-parameter supervised pretraining; returns params with fresh
     (zero-delta) LoRA adapters on top — the shared W' + θ_0 of eq. 1."""
     key = (cfg.name, cfg.num_layers, cfg.d_model, steps, lr, seed, len(pretrain_data),
-           num_classes, batch_size, last_only)
+           num_classes, batch_size)
     if key in _CACHE:
         return _owned(_CACHE[key])
 
     params = model_init(jax.random.PRNGKey(seed), cfg)
     opt = adamw_init(params, state_dtype=cfg.optimizer_state_dtype)
-    step = _supervised_step(cfg, num_classes, lr, last_only)
+    step = _supervised_step(cfg, num_classes, lr)
     rng = np.random.default_rng(seed)
     done = 0
     metrics = {}

@@ -34,7 +34,7 @@ from repro.core.protocol import CommLedger, RoundStats, downlink_bits
 from repro.data.partition import dirichlet_partition, iid_partition, split_public_private
 from repro.data.synthetic import IntentDataset
 from repro.fed.client import Client, make_upload_payload
-from repro.fed.engine import BroadcastState, cohort_budgets, make_engine
+from repro.fed.engines import BroadcastState, cohort_budgets, make_engine
 from repro.fed.server import Server
 from repro.fed.steps import EVAL_BATCH, make_eval_fn
 
@@ -64,10 +64,6 @@ class FedConfig:
     # round is one compiled call); "sequential" is the bit-compatible
     # one-client-at-a-time reference.
     engine: Engine = "batched"
-    # Compute the LM head (class/public/distill logits) on the LAST position
-    # only — the task reads nothing else; cuts head FLOPs ~seq_len×.  False
-    # restores the seed behaviour of materialising (B, T, V).
-    last_only: bool = True
     # Fused engines: place the client axis over jax devices (shard_map).  For
     # "fused_e2e" the placement lives INSIDE the whole-round executable (the
     # server phase stays replicated); odd cohorts are padded with masked
@@ -322,15 +318,13 @@ def run_federated(
                 client_inits[fam] = pretrain_classifier(
                     fam, pretrain_ds, num_classes=dataset.num_classes,
                     steps=fed.pretrain_steps, lr=fed.pretrain_lr,
-                    seed=fed.seed + 17 * fi,
-                    last_only=fed.last_only, verbose=verbose,
+                    seed=fed.seed + 17 * fi, verbose=verbose,
                 )
             if fed.server_pretrain == "supervised":
                 server_init = pretrain_classifier(
                     server_cfg, pretrain_ds, num_classes=dataset.num_classes,
                     steps=fed.server_pretrain_steps, lr=fed.pretrain_lr,
-                    seed=fed.seed + 999, last_only=fed.last_only,
-                    verbose=verbose,
+                    seed=fed.seed + 999, verbose=verbose,
                 )
             elif fed.server_pretrain == "lm":
                 server_init = pretrain_lm(
@@ -360,7 +354,6 @@ def run_federated(
             local_steps=fed.local_steps,
             distill_steps=fed.distill_steps,
             restrict_to_support=fed.restrict_to_support,
-            last_only=fed.last_only,
             initial_params=client_inits.get(cfgs[i]),
         )
         for i in range(fed.num_clients)
@@ -375,7 +368,6 @@ def run_federated(
         distill_steps=fed.server_distill_steps,
         use_kernels=fed.use_kernels,
         restrict_to_support=fed.restrict_to_support,
-        last_only=fed.last_only,
         initial_params=server_init,
     )
     channel_cfg = fed.channel
@@ -394,12 +386,9 @@ def run_federated(
     # data only in expectation at reduced scale; standard FedD evaluation)
     eval_idx = rng.permutation(len(private))[: fed.eval_size]
     eval_tokens, eval_labels = private.tokens[eval_idx], private.labels[eval_idx]
-    evaluate = make_eval_fn(server_cfg, dataset.num_classes, last_only=fed.last_only)
+    evaluate = make_eval_fn(server_cfg, dataset.num_classes)
     # per-family client evaluators (make_eval_fn is lru-cached per config)
-    evaluate_client = {
-        fam: make_eval_fn(fam, dataset.num_classes, last_only=fed.last_only)
-        for fam in families
-    }
+    evaluate_client = {fam: make_eval_fn(fam, dataset.num_classes) for fam in families}
 
     engine = make_engine(
         fed.engine,
@@ -415,7 +404,6 @@ def run_federated(
         restrict_to_support=fed.restrict_to_support,
         value_bits=fed.channel.value_bits,
         k_min=fed.channel.min_k,
-        last_only=fed.last_only,
         shard_clients=fed.shard_clients,
         use_kernels=fed.use_kernels,
         quantize_wire=fed.quantize_wire,

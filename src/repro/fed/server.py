@@ -31,19 +31,17 @@ class Server:
         distill_steps: int = 2,
         use_kernels: bool = False,
         restrict_to_support: bool = False,
-        last_only: bool = True,
         initial_params=None,
     ):
         self.cfg = cfg
         self.aggregation: AggregationMode = aggregation
         self.distill_steps = distill_steps
         self.use_kernels = use_kernels
-        self.last_only = last_only
         self.params = initial_params if initial_params is not None else model_init(jax.random.PRNGKey(seed), cfg)
         self.opt = fed_steps.init_lora_opt(self.params, cfg)
         self._distill_step = fed_steps.make_distill_step(
             cfg, lr=distill_lr, temperature=temperature, lam=lam,
-            restrict_to_support=restrict_to_support, last_only=last_only,
+            restrict_to_support=restrict_to_support,
         )
 
     # ---- Algorithm 1, line 15: aggregate client knowledge ----
@@ -120,9 +118,7 @@ class Server:
         """Returns (K_down, h_down, downlink_bits).  The paper's workflow:
         after the server-side distillation update, the server re-infers the
         public set and broadcasts its logits + LoRA projection."""
-        logits, h = fed_steps.public_logits(
-            self.params, self.cfg, public_tokens, last_only=self.last_only
-        )
+        logits, h = fed_steps.public_logits(self.params, self.cfg, public_tokens)
         rank = self.cfg.lora.rank if (self.cfg.lora is not None and h is not None) else None
         bits = downlink_bits(logits.shape[0], logits.shape[-1], rank)
         return logits, h, bits

@@ -70,41 +70,26 @@ def _cast_params(params, compute_dtype: str):
     )
 
 
-def last_logits(
-    params,
-    cfg: ModelConfig,
-    batch: dict,
-    *,
-    last_only: bool = True,
-    head_cols: int | None = None,
-):
-    """(B, V) last-position logits + Aux, via the cheap head when enabled.
+def last_logits(params, cfg: ModelConfig, batch: dict, *, head_cols: int | None = None):
+    """(B, V) last-position logits + Aux.
 
-    ``last_only=True`` (default) computes the LM head on the final hidden
-    state only — a ~seq_len× cut in head FLOPs/memory, which dominates at
-    the paper's 50k+ vocabularies; ``False`` keeps the seed behaviour of
-    materialising (B, T, V) and slicing (the PR-1 reference, benchmarked
-    against in benchmarks/engine_bench.py — ``head_cols`` is ignored there
-    so the historical reference keeps its full cost).
-
-    ``head_cols=k`` (with ``last_only``) computes only the first k head
-    columns — bit-identical to slicing, at k/V of the head FLOPs; the
-    supervised class losses/eval read ``num_classes`` columns only.
+    The LM head runs on the final hidden state only — a ~seq_len× cut in
+    head FLOPs/memory, which dominates at the paper's 50k+ vocabularies.
+    ``head_cols=k`` computes only the first k head columns — bit-identical
+    to slicing, at k/V of the head FLOPs; the supervised class losses/eval
+    read ``num_classes`` columns only.
     """
-    if last_only:
-        return forward(params, cfg, batch, last_only=True, head_cols=head_cols)
-    logits, aux = forward(params, cfg, batch)
-    return logits[:, -1, :], aux
+    return forward(params, cfg, batch, last_only=True, head_cols=head_cols)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "last_only"))
-def public_logits(params, cfg: ModelConfig, tokens: jax.Array, *, last_only: bool = True):
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def public_logits(params, cfg: ModelConfig, tokens: jax.Array):
     """Last-position vocab logits + pooled LoRA projection on a public batch.
 
     Returns (logits (B, V), h (B, r) or None) — the client/server upload
     content (Algorithm 1 lines 4, 14).
     """
-    logits, aux = last_logits(params, cfg, {"tokens": tokens}, last_only=last_only)
+    logits, aux = last_logits(params, cfg, {"tokens": tokens})
     return logits, aux.lora_h
 
 
@@ -114,27 +99,19 @@ def init_lora_opt(params, cfg: ModelConfig) -> AdamWState:
 
 
 def _finetune_loss_fn(
-    cfg: ModelConfig,
-    num_classes: int,
-    last_only: bool = True,
-    class_head_only: bool = True,
-    compute_dtype: str = "float32",
+    cfg: ModelConfig, num_classes: int, compute_dtype: str = "float32"
 ) -> Callable:
     """loss(lora, frozen, batch) -> (nll + moe_aux, acc) — the shared core
     of the sequential step, the batched cohort step and the fused round.
 
-    The supervised loss reads ``num_classes`` class logits only, so the
-    last-only path restricts the LM head to those columns (``head_cols`` —
-    bit-identical logits/gradients at num_classes/V of the head FLOPs).
-    ``class_head_only=False`` restores the full-vocab head of the PR-2
-    pipeline (kept benchable as the historical reference, like the PR-1
-    full-(B,T,V) head before it)."""
+    The supervised loss reads ``num_classes`` class logits only, so the LM
+    head is restricted to those columns (``head_cols`` — bit-identical
+    logits/gradients at num_classes/V of the head FLOPs)."""
 
     def loss_fn(lora, frozen, batch):
         params = _cast_params(merge_lora(lora, frozen), compute_dtype)
         last, aux = last_logits(
-            params, cfg, {"tokens": batch["tokens"]}, last_only=last_only,
-            head_cols=num_classes if (last_only and class_head_only) else None,
+            params, cfg, {"tokens": batch["tokens"]}, head_cols=num_classes
         )
         cls = class_logits(last, num_classes)
         logp = jax.nn.log_softmax(cls.astype(jnp.float32), axis=-1)
@@ -150,12 +127,10 @@ def _finetune_step_fn(
     num_classes: int,
     lr: float,
     weight_decay: float,
-    last_only: bool = True,
-    class_head_only: bool = True,
 ) -> Callable:
     """Unjitted single-client fine-tune step over merged params."""
 
-    loss_fn = _finetune_loss_fn(cfg, num_classes, last_only, class_head_only)
+    loss_fn = _finetune_loss_fn(cfg, num_classes)
 
     def step(params, opt, batch):
         lora, frozen = split_lora(params)
@@ -175,16 +150,12 @@ def make_finetune_step(
     *,
     lr: float = 1e-3,
     weight_decay: float = 1e-3,
-    last_only: bool = True,
-    class_head_only: bool = True,
 ) -> Callable:
     """Supervised local fine-tuning on private data (paper eq. 2), LoRA-only.
 
     step(params, opt, batch{tokens,labels}) -> (params, opt, metrics)
     """
-    return jax.jit(
-        _finetune_step_fn(cfg, num_classes, lr, weight_decay, last_only, class_head_only)
-    )
+    return jax.jit(_finetune_step_fn(cfg, num_classes, lr, weight_decay))
 
 
 @functools.lru_cache(maxsize=64)
@@ -195,8 +166,6 @@ def make_batched_finetune_step(
     lr: float = 1e-3,
     weight_decay: float = 1e-3,
     shared_backbone: bool = True,
-    last_only: bool = True,
-    class_head_only: bool = True,
 ) -> Callable:
     """One fine-tune update for a whole cohort at once.
 
@@ -213,7 +182,7 @@ def make_batched_finetune_step(
     comes from.  LoRA/opt buffers are donated.
     """
 
-    loss_fn = _finetune_loss_fn(cfg, num_classes, last_only, class_head_only)
+    loss_fn = _finetune_loss_fn(cfg, num_classes)
 
     def step(lora, frozen, opt, batch):
         (loss, acc), grads = jax.value_and_grad(loss_fn, has_aux=True)(lora, frozen, batch)
@@ -231,7 +200,6 @@ def _distill_loss_fn(
     temperature: float,
     lam: float,
     restrict_to_support: bool,
-    last_only: bool = True,
     compute_dtype: str = "float32",
 ) -> Callable:
     """loss(lora, frozen, tokens, g_logits, g_h) -> (L_total, parts)."""
@@ -240,7 +208,7 @@ def _distill_loss_fn(
 
     def loss_fn(lora, frozen, tokens, g_logits, g_h):
         params = _cast_params(merge_lora(lora, frozen), compute_dtype)
-        own, aux = last_logits(params, cfg, {"tokens": tokens}, last_only=last_only)
+        own, aux = last_logits(params, cfg, {"tokens": tokens})
         loss, parts = total_distill_loss(
             g_logits,
             own,
@@ -259,7 +227,6 @@ def _distill_loss_cached_fn(
     cfg: ModelConfig,
     temperature: float,
     lam: float,
-    last_only: bool = True,
     compute_dtype: str = "float32",
 ) -> Callable:
     """loss(lora, frozen, tokens, t_logp, th_logp, support_mask) with the
@@ -274,7 +241,7 @@ def _distill_loss_cached_fn(
 
     def loss_fn(lora, frozen, tokens, t_logp, th_logp, support_mask):
         params = _cast_params(merge_lora(lora, frozen), compute_dtype)
-        own, aux = last_logits(params, cfg, {"tokens": tokens}, last_only=last_only)
+        own, aux = last_logits(params, cfg, {"tokens": tokens})
         loss = kl_divergence_from_log_probs(
             t_logp, own, temperature, mask=support_mask
         )
@@ -293,11 +260,10 @@ def _distill_step_fn(
     temperature: float,
     lam: float,
     restrict_to_support: bool,
-    last_only: bool = True,
 ) -> Callable:
     """Unjitted single-model distillation step over merged params."""
 
-    loss_fn = _distill_loss_fn(cfg, temperature, lam, restrict_to_support, last_only)
+    loss_fn = _distill_loss_fn(cfg, temperature, lam, restrict_to_support)
 
     def step(params, opt, tokens, g_logits, g_h):
         lora, frozen = split_lora(params)
@@ -318,7 +284,6 @@ def make_distill_step(
     temperature: float = 2.0,
     lam: float = 0.03,
     restrict_to_support: bool = False,
-    last_only: bool = True,
 ) -> Callable:
     """Knowledge-distillation update against global teacher knowledge
     (Algorithm 1 lines 5-7 / 16): LoRA-only gradient on L_total (eq. 10).
@@ -326,9 +291,7 @@ def make_distill_step(
     step(params, opt, public_tokens, g_logits, g_h) -> (params, opt, metrics)
     ``g_h`` may be None -> the λ-term drops (the 'Adaptive' baseline).
     """
-    return jax.jit(
-        _distill_step_fn(cfg, lr, temperature, lam, restrict_to_support, last_only)
-    )
+    return jax.jit(_distill_step_fn(cfg, lr, temperature, lam, restrict_to_support))
 
 
 @functools.lru_cache(maxsize=64)
@@ -340,7 +303,6 @@ def make_batched_distill_step(
     lam: float = 0.03,
     restrict_to_support: bool = False,
     shared_backbone: bool = True,
-    last_only: bool = True,
 ) -> Callable:
     """Cohort distillation against one broadcast teacher.
 
@@ -352,7 +314,7 @@ def make_batched_distill_step(
     Algorithm 1 lines 5-7; with ``shared_backbone`` the frozen W' is
     broadcast too (see :func:`make_batched_finetune_step`).
     """
-    loss_fn = _distill_loss_fn(cfg, temperature, lam, restrict_to_support, last_only)
+    loss_fn = _distill_loss_fn(cfg, temperature, lam, restrict_to_support)
 
     def step(lora, frozen, opt, tokens, g_logits, g_h):
         (loss, parts), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -369,17 +331,13 @@ def make_batched_distill_step(
 
 
 @functools.lru_cache(maxsize=64)
-def make_batched_public_logits(
-    cfg: ModelConfig, *, shared_backbone: bool = True, last_only: bool = True
-) -> Callable:
+def make_batched_public_logits(cfg: ModelConfig, *, shared_backbone: bool = True) -> Callable:
     """Cohort public-set inference: (lora (C,...), frozen, tokens (P,L)) ->
     (logits (C,P,V), h (C,P,r) or None) — Algorithm 1 line 9 for the whole
     round's selected clients in one compiled call."""
 
     def one(lora, frozen, tokens):
-        last, aux = last_logits(
-            merge_lora(lora, frozen), cfg, {"tokens": tokens}, last_only=last_only
-        )
+        last, aux = last_logits(merge_lora(lora, frozen), cfg, {"tokens": tokens})
         return last, aux.lora_h
 
     frozen_ax = None if shared_backbone else 0
@@ -398,10 +356,8 @@ def _client_round_core(
     restrict_to_support: bool,
     local_steps: int,
     distill_steps: int,
-    last_only: bool,
     gate_distill: bool,
     kd_loss: Callable | None = None,
-    class_head_only: bool = True,
     compute_dtype: str = "float32",
 ) -> Callable:
     """Per-client round body shared by the fused and fused-e2e round fns:
@@ -423,12 +379,10 @@ def _client_round_core(
     round passes precomputed teacher log-probs into
     :func:`_distill_loss_cached_fn` instead).
     """
-    ft_loss = _finetune_loss_fn(
-        cfg, num_classes, last_only, class_head_only, compute_dtype
-    )
+    ft_loss = _finetune_loss_fn(cfg, num_classes, compute_dtype)
     if kd_loss is None:
         kd_loss = _distill_loss_fn(
-            cfg, temperature, lam, restrict_to_support, last_only, compute_dtype
+            cfg, temperature, lam, restrict_to_support, compute_dtype
         )
 
     def client_round(lora, frozen, opt, g_tokens, kd_args, g_valid, batches, pub_tokens):
@@ -458,7 +412,7 @@ def _client_round_core(
         # -- line 9: public last-position inference --
         last, aux = last_logits(
             _cast_params(merge_lora(lora, frozen), compute_dtype), cfg,
-            {"tokens": pub_tokens}, last_only=last_only,
+            {"tokens": pub_tokens},
         )
         return lora, opt, last, aux.lora_h
 
@@ -479,9 +433,7 @@ def make_fused_round_fn(
     local_steps: int = 4,
     distill_steps: int = 2,
     shared_backbone: bool = True,
-    last_only: bool = True,
     use_kernels: bool = False,
-    class_head_only: bool = True,
     compute_dtype: str = "float32",
 ) -> Callable:
     """The whole client phase of Algorithm 1 as ONE function.
@@ -511,9 +463,8 @@ def make_fused_round_fn(
     client_round = _client_round_core(
         cfg, num_classes, lr=lr, weight_decay=weight_decay, distill_lr=distill_lr,
         temperature=temperature, lam=lam, restrict_to_support=restrict_to_support,
-        local_steps=local_steps, distill_steps=distill_steps, last_only=last_only,
-        gate_distill=False, class_head_only=class_head_only,
-        compute_dtype=compute_dtype,
+        local_steps=local_steps, distill_steps=distill_steps,
+        gate_distill=False, compute_dtype=compute_dtype,
     )
 
     frozen_ax = None if shared_backbone else 0
@@ -573,7 +524,6 @@ def make_bucket_client_phase_fn(
     local_steps: int = 4,
     distill_steps: int = 2,
     shared_backbone: bool = True,
-    last_only: bool = True,
     quantize: bool = False,
     compute_dtype: str = "float32",
 ) -> Callable:
@@ -597,7 +547,7 @@ def make_bucket_client_phase_fn(
     low-precision compute).
 
     This is the per-bucket executable of the heterogeneous round engine
-    (:class:`repro.fed.engine.HeteroFusedE2EEngine`): the fleet is
+    (:class:`repro.fed.engines.HeteroFusedE2EEngine`): the fleet is
     partitioned into homogeneous family buckets (`repro.fed.cohort`), each
     bucket runs this function with its own ``cfg``/backbone layout
     (``shared_backbone=False`` stacks the frozen trees on the client axis —
@@ -610,14 +560,12 @@ def make_bucket_client_phase_fn(
     the cold-server round is DATA (``g_valid``), one executable serves every
     round of a run (per ``k_cap`` bucket).
     """
-    cached_kd = _distill_loss_cached_fn(
-        cfg, temperature, lam, last_only, compute_dtype
-    )
+    cached_kd = _distill_loss_cached_fn(cfg, temperature, lam, compute_dtype)
     client_round = _client_round_core(
         cfg, num_classes, lr=lr, weight_decay=weight_decay,
         distill_lr=distill_lr, temperature=temperature, lam=lam,
         restrict_to_support=restrict_to_support, local_steps=local_steps,
-        distill_steps=distill_steps, last_only=last_only, gate_distill=True,
+        distill_steps=distill_steps, gate_distill=True,
         kd_loss=cached_kd, compute_dtype=compute_dtype,
     )
     frozen_ax = None if shared_backbone else 0
@@ -653,7 +601,6 @@ def make_server_phase_fn(
     server_distill_steps: int = 12,
     aggregation: AggregationMode = "adaptive",
     send_h: bool = True,
-    last_only: bool = True,
     use_kernels: bool = False,
     quantize: bool = False,
     compute_dtype: str = "float32",
@@ -682,9 +629,7 @@ def make_server_phase_fn(
     DATA and reports a NaN ``d_loss``; the broadcast still refreshes on the
     current public batch, exactly like the host round loop.
     """
-    server_kd_loss = _distill_loss_cached_fn(
-        server_cfg, temperature, lam, last_only, compute_dtype
-    )
+    server_kd_loss = _distill_loss_cached_fn(server_cfg, temperature, lam, compute_dtype)
     teacher_cache = _teacher_cache_fn(temperature, restrict_to_support, True)
 
     def fn(s_lora, s_frozen, s_opt, values, indices, mask, scale, h, ks,
@@ -739,7 +684,7 @@ def make_server_phase_fn(
         # -- lines 1-2 of the NEXT round: refreshed broadcast knowledge --
         b_last, b_aux = last_logits(
             _cast_params(merge_lora(s_lora, s_frozen), compute_dtype),
-            server_cfg, {"tokens": pub_tokens}, last_only=last_only,
+            server_cfg, {"tokens": pub_tokens},
         )
         return s_lora, s_opt, b_last, b_aux.lora_h, d_loss
 
@@ -765,7 +710,6 @@ def make_fused_e2e_round_fn(
     aggregation: AggregationMode = "adaptive",
     send_h: bool = True,
     shared_backbone: bool = True,
-    last_only: bool = True,
     use_kernels: bool = False,
     shard_clients: bool = False,
     quantize: bool = False,
@@ -838,14 +782,12 @@ def make_fused_e2e_round_fn(
     teacher carries no gradient).
     """
     use_h = client_cfg.lora is not None
-    cached_kd = _distill_loss_cached_fn(
-        client_cfg, temperature, lam, last_only, compute_dtype
-    )
+    cached_kd = _distill_loss_cached_fn(client_cfg, temperature, lam, compute_dtype)
     client_round = _client_round_core(
         client_cfg, num_classes, lr=lr, weight_decay=weight_decay,
         distill_lr=distill_lr, temperature=temperature, lam=lam,
         restrict_to_support=restrict_to_support, local_steps=local_steps,
-        distill_steps=distill_steps, last_only=last_only, gate_distill=True,
+        distill_steps=distill_steps, gate_distill=True,
         kd_loss=cached_kd, compute_dtype=compute_dtype,
     )
     frozen_ax = None if shared_backbone else 0
@@ -858,7 +800,7 @@ def make_fused_e2e_round_fn(
         temperature=temperature, lam=lam,
         restrict_to_support=restrict_to_support,
         server_distill_steps=server_distill_steps, aggregation=aggregation,
-        send_h=send_h, last_only=last_only, use_kernels=use_kernels,
+        send_h=send_h, use_kernels=use_kernels,
         quantize=quantize, compute_dtype=compute_dtype,
     )
 
@@ -916,17 +858,14 @@ def make_fused_e2e_round_fn(
 EVAL_BATCH = 64
 
 
-def _eval_correct_fn(cfg: ModelConfig, num_classes: int, last_only: bool) -> Callable:
+def _eval_correct_fn(cfg: ModelConfig, num_classes: int) -> Callable:
     """correct(params, tokens, labels) -> () float32 count of correct
     last-position class predictions — the ONE copy of the eval math shared
     by the host-side batched evaluator and the in-scan eval tap (their 1e-6
     parity contract rests on this being literally the same function)."""
 
     def correct(params, tokens, labels):
-        last, _ = last_logits(
-            params, cfg, {"tokens": tokens}, last_only=last_only,
-            head_cols=num_classes if last_only else None,
-        )
+        last, _ = last_logits(params, cfg, {"tokens": tokens}, head_cols=num_classes)
         cls = class_logits(last, num_classes)
         return jnp.sum((jnp.argmax(cls, -1) == labels).astype(jnp.float32))
 
@@ -934,9 +873,7 @@ def _eval_correct_fn(cfg: ModelConfig, num_classes: int, last_only: bool) -> Cal
 
 
 @functools.lru_cache(maxsize=64)
-def make_scan_eval_fn(
-    cfg: ModelConfig, num_classes: int, *, last_only: bool = True
-) -> Callable:
+def make_scan_eval_fn(cfg: ModelConfig, num_classes: int) -> Callable:
     """Traceable accuracy for the in-scan eval tap (``run_rounds``).
 
     acc(lora, frozen, tokens (B, L), labels (B,)) -> () float32 — the same
@@ -949,7 +886,7 @@ def make_scan_eval_fn(
     compiled program (the per-chunk correct-counts are integers, so the
     chunked sum is exact).
     """
-    correct = _eval_correct_fn(cfg, num_classes, last_only)
+    correct = _eval_correct_fn(cfg, num_classes)
 
     def acc(lora, frozen, tokens, labels):
         params = merge_lora(lora, frozen)
@@ -980,11 +917,10 @@ def make_eval_fn(
     num_classes: int,
     *,
     batch_size: int = EVAL_BATCH,
-    last_only: bool = True,
 ) -> Callable:
     """Accuracy over an IntentDataset (numpy arrays), batched + jitted."""
 
-    batch_acc = jax.jit(_eval_correct_fn(cfg, num_classes, last_only))
+    batch_acc = jax.jit(_eval_correct_fn(cfg, num_classes))
 
     def evaluate(params, tokens, labels) -> float:
         n = tokens.shape[0]

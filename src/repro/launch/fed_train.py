@@ -60,9 +60,6 @@ def main(argv=None) -> int:
                          "fused_e2e = one jitted call for the WHOLE round — "
                          "sparse-wire aggregation + server distill + "
                          "broadcast folded in)")
-    ap.add_argument("--full-head", action="store_true",
-                    help="materialise full (B,T,V) logits instead of the "
-                         "last-only LM head (the pre-PR-2 behaviour)")
     ap.add_argument("--shard-clients", action="store_true",
                     help="fused/fused_e2e engines: place the client axis "
                          "over jax devices via shard_map (for fused_e2e the "
@@ -158,7 +155,6 @@ def main(argv=None) -> int:
         use_kernels=args.use_kernels,
         quantize_wire=args.quantize_wire,
         compute_dtype=args.compute_dtype,
-        last_only=not args.full_head,
         shard_clients=args.shard_clients,
         scan_rounds=args.scan_rounds,
         scenario=args.scenario,

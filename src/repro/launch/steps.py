@@ -1,17 +1,12 @@
-"""Production step functions lowered by the dry-run and drivers.
+"""Training step functions lowered by the dry-run and drivers.
 
-  train_step   — next-token LM loss (chunked CE: (B,S,V) logits never
-                 materialise), full-param AdamW, optional gradient-
-                 accumulation microbatches (cfg.microbatches).
-  prefill_step — full forward, last-position logits (B, V).
-  serve_step   — one decode token against the KV/SSM cache.
+  train_step — next-token LM loss (chunked CE: (B,S,V) logits never
+               materialise), full-param AdamW, optional gradient-
+               accumulation microbatches (cfg.microbatches).
 
-All are pure (params/opt/batch in, params/opt/metrics out) and
-pjit-compatible; shardings are attached by the caller (dryrun/train).
-
-The serving-side factories (prefill/serve) live in :mod:`repro.serve.steps`
-since the PR-10 serve redesign; ``make_serve_step``/``make_prefill_step``
-stay importable here as shims (``make_serve_step`` == ``make_decode_step``).
+Pure (params/opt/batch in, params/opt/metrics out) and pjit-compatible;
+shardings are attached by the caller (dryrun/train).  The serving step
+factories live in :mod:`repro.serve.steps`.
 """
 
 from __future__ import annotations
@@ -26,14 +21,7 @@ from repro.models import backbone
 from repro.models.model import _lm_logits  # internal head reuse (framework-private)
 from repro.optim import AdamWState, adamw_update
 
-# Serving steps moved to repro.serve (PR 10 api_redesign) — re-exported here
-# so pre-redesign imports keep working, mirroring the PR-9 fed/engines shims.
-from repro.serve.steps import (  # noqa: F401
-    make_decode_step as make_serve_step,
-    make_prefill_step,
-)
-
-__all__ = ["chunked_lm_loss", "make_train_step", "make_prefill_step", "make_serve_step"]
+__all__ = ["chunked_lm_loss", "make_train_step"]
 
 CE_CHUNK = 512  # sequence positions per cross-entropy chunk
 

@@ -4,7 +4,7 @@ scenarios.
 The keystone of the batched/fused client-execution engines: under the same
 seed the engines must agree round-for-round — identical per-client adaptive
 k, identical ledger bytes, matching accuracies (sequential↔batched bitwise;
-the fused single-jit body is tolerance-compatible, see fed/engine.py).
+the fused single-jit body is tolerance-compatible, see fed/engines/__init__.py).
 Tiny configs (no backbone pretraining) keep this in the fast tier.
 """
 
@@ -33,6 +33,7 @@ from repro.fed import (
     run_federated,
 )
 from repro.fed.client import Client
+from repro.fed.engines import k_cap_bucket
 from repro.fed.server import Server
 
 LORA = LoRAConfig(rank=4, alpha=32.0, dropout=0.0, targets=("q", "v", "head"))
@@ -311,6 +312,10 @@ def test_fused_e2e_sparse_wire_matches_dense_uplink():
     assert pe.dense is None  # no densified stack exists on this path
     wire = pe.sparse
     assert wire.values.shape[0] == 2  # transmitters only
+    # static width: the power-of-two bucket of the round's largest k, at
+    # 9 bytes an entry (f32 value, i32 index, bool mask)
+    assert wire.values.shape[-1] == k_cap_bucket(pe.ks, CLIENT.vocab_size)
+    assert sum(a.dtype.itemsize for a in (wire.values, wire.indices, wire.mask)) == 9
     # per-row transmitted-entry counts == the adaptive budgets
     counts = np.asarray(jnp.sum(wire.mask, axis=-1))
     assert set(np.unique(counts[0])) == {pe.ks[0]}
@@ -327,6 +332,17 @@ def test_fused_e2e_sparse_wire_matches_dense_uplink():
         np.asarray(k_g_wire), np.asarray(k_g_dense), rtol=1e-5, atol=1e-6
     )
     np.testing.assert_allclose(np.asarray(h_g), np.asarray(h_g_dense), atol=0)
+
+
+def test_k_cap_bucket_is_next_power_of_two():
+    """The wire's width: the next power of two >= the round's largest k,
+    at least 1, at most the vocabulary."""
+    assert k_cap_bucket([0, 0], 256) == 1
+    assert k_cap_bucket([3, 5, 0], 256) == 8
+    assert k_cap_bucket([64], 256) == 64
+    assert k_cap_bucket([65, 2], 256) == 128
+    assert k_cap_bucket([200], 256) == 256
+    assert k_cap_bucket([90], 100) == 100
 
 
 def test_fused_e2e_run_rounds_matches_per_round():
@@ -400,9 +416,9 @@ def test_e2e_aggregation_path_never_densifies_stack():
     """Trace-inspection acceptance check: at bench-like shapes, no
     intermediate of the sparse aggregation path — sub-jaxprs included —
     reaches the (N, B, V) dense stack's element count; the working set is
-    O(N·B·k_cap) + the single (B, V) output.  Same shared inspection
-    (max_intermediate_elems) as the BENCH_round.json record, for both the
-    pure-jnp scatter and the Pallas kernel route."""
+    O(N·B·k_cap) + the single (B, V) output.  Checked with the shared
+    inspection (max_intermediate_elems), for both the pure-jnp scatter and
+    the Pallas kernel route."""
     import jax
 
     from repro.core.aggregation import aggregate_wire, max_intermediate_elems
@@ -438,7 +454,7 @@ _SHARD_MAP_SCRIPT = textwrap.dedent(
     from repro.core.channel import BatchedChannelState, ChannelState
     from repro.data import make_banking77_like
     from repro.fed.client import Client
-    from repro.fed.engine import FusedEngine
+    from repro.fed.engines import FusedEngine
 
     lora = LoRAConfig(rank=4, alpha=32.0, dropout=0.0, targets=("q", "v", "head"))
     cfg = REDUCED_CLIENT.with_overrides(
@@ -514,7 +530,7 @@ _E2E_SHARD_SCRIPT = textwrap.dedent(
     from repro.core import ChannelConfig, ChannelSimulator
     from repro.data import make_banking77_like
     from repro.fed.client import Client
-    from repro.fed.engine import FusedE2EEngine
+    from repro.fed.engines import FusedE2EEngine
     from repro.fed.server import Server
     from repro.models import init as model_init
 

@@ -7,9 +7,20 @@ no-pretrain configs — the end-to-end contracts: the "none" preset is
 bit-identical to faults=None on every engine path, fault realisations agree
 engine-for-engine (same k, bytes, quarantine counts), and the corruption
 preset actually engages.
+
+The golden trajectory — a committed tiny-fault record
+(tests/data/faults_golden.json: per-round k / uplink bytes / quarantine and
+crash counts / retransmission bytes for the corruption, crashes and lossy
+presets at fixed seed) asserted identical between the per-round host loop
+and the one-dispatch ``scan_rounds`` run.  Regenerate it (only after an
+intentional change to the wire, its pricing or the fault streams):
+
+    PYTHONPATH=src python tests/test_faults.py --regen
 """
 
 import dataclasses
+import json
+import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -480,3 +491,74 @@ def test_scan_rounds_fault_parity():
     assert scan.retrans_bytes == host.retrans_bytes
     for ra, rb in zip(scan.ledger.rounds, host.ledger.rounds):
         assert ra.uplink_bytes == rb.uplink_bytes
+
+
+# ---------------------------------------------------------------------------
+# golden trajectory: host loop vs one-dispatch scan, committed record
+# ---------------------------------------------------------------------------
+
+_GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "data", "faults_golden.json")
+_GOLDEN_PRESETS = ("corruption", "crashes", "lossy")
+_GOLDEN_ROUNDS = 3
+_GOLDEN_FIELDS = ("ks", "uplink_bytes", "num_quarantined", "num_crashed", "retrans_bytes")
+
+
+def _golden_record(run) -> dict:
+    return {
+        "ks": [[int(k) for k in ks] for ks in run.per_client_k],
+        "uplink_bytes": [float(r.uplink_bytes) for r in run.ledger.rounds],
+        "num_quarantined": [int(n) for n in run.num_quarantined],
+        "num_crashed": [int(n) for n in run.num_crashed],
+        "retrans_bytes": [float(b) for b in run.retrans_bytes],
+    }
+
+
+def _golden_runs(preset: str):
+    """The tiny fault scenario as a per-round host loop and as one scan."""
+    ds = _dataset()
+    cfg = _cfg("fused_e2e", rounds=_GOLDEN_ROUNDS, faults=preset)
+    host = run_federated(CLIENT, SERVER, ds, cfg)
+    scan = run_federated(CLIENT, SERVER, ds, dataclasses.replace(cfg, scan_rounds=True))
+    return host, scan
+
+
+@pytest.mark.parametrize("preset", _GOLDEN_PRESETS)
+def test_golden_trajectory_host_vs_scan(preset):
+    with open(_GOLDEN_PATH) as f:
+        golden = json.load(f)[preset]
+    host, scan = _golden_runs(preset)
+    # host loop == scan == the committed record, exactly
+    assert _golden_record(host) == _golden_record(scan) == golden
+    assert all(0.0 <= a <= 1.0 for a in host.server_acc + scan.server_acc)
+
+
+def test_golden_record_is_current():
+    """The committed record covers exactly the golden presets, one entry a
+    round, and every preset engages its faults (catches a stale file after
+    an intentional regeneration)."""
+    with open(_GOLDEN_PATH) as f:
+        golden = json.load(f)
+    assert set(golden) == set(_GOLDEN_PRESETS)
+    for rec in golden.values():
+        assert set(rec) == set(_GOLDEN_FIELDS)
+        assert all(len(rec[field]) == _GOLDEN_ROUNDS for field in _GOLDEN_FIELDS)
+    assert sum(golden["corruption"]["num_quarantined"]) > 0
+    assert sum(golden["corruption"]["retrans_bytes"]) > 0
+    assert sum(golden["crashes"]["num_crashed"]) > 0
+
+
+if __name__ == "__main__":
+    import sys
+
+    from test_scenario import write_golden
+
+    if "--regen" in sys.argv:
+        record = {}
+        for preset in _GOLDEN_PRESETS:
+            host, scan = _golden_runs(preset)
+            assert _golden_record(host) == _golden_record(scan), preset
+            record[preset] = _golden_record(host)
+        write_golden(_GOLDEN_PATH, record)
+        print(f"wrote {_GOLDEN_PATH}")
+    else:
+        print("usage: PYTHONPATH=src python tests/test_faults.py --regen")

@@ -24,7 +24,7 @@ from repro.data import make_banking77_like, make_fed_benchmark_dataset, split_pu
 from repro.fed import FedConfig, run_federated
 from repro.fed.client import Client
 from repro.fed.cohort import partition_fleet, split_cohort, validate_family_contracts
-from repro.fed.engine import HeteroFusedE2EEngine, SequentialEngine
+from repro.fed.engines import HeteroFusedE2EEngine, SequentialEngine
 from repro.fed.server import Server
 
 # ---------------------------------------------------------------------------

@@ -85,16 +85,17 @@ def test_last_only_matches_on_reduced_client_lora():
 
 
 def test_public_logits_modes_agree():
-    """public_logits(last_only=True) — the upload content — equals the seed
-    path that materialised (B, T, V) and sliced."""
+    """public_logits — the upload content, computed on the last position
+    only — equals the last position of the full (B, T, V) forward."""
     cfg = REDUCED_CLIENT.with_overrides(
         num_layers=2, d_model=64, num_heads=2, num_kv_heads=2, d_ff=128,
         vocab_size=256, max_seq_len=32, lora=LORA,
     )
     params = init(jax.random.PRNGKey(2), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(4), (4, 12), 0, cfg.vocab_size)
-    fast_logits, fast_h = public_logits(params, cfg, tokens, last_only=True)
-    slow_logits, slow_h = public_logits(params, cfg, tokens, last_only=False)
+    fast_logits, fast_h = public_logits(params, cfg, tokens)
+    full, aux_full = forward(params, cfg, {"tokens": tokens}, last_only=False)
+    slow_logits, slow_h = full[:, -1, :], aux_full.lora_h
     np.testing.assert_allclose(
         np.asarray(fast_logits), np.asarray(slow_logits), rtol=1e-5, atol=1e-6
     )

@@ -14,7 +14,7 @@ Three layers of guarantees:
    extended to stateful channels).
 3. The golden trajectory — a committed tiny-scenario record
    (tests/data/scenario_golden.json: per-round k / payload bytes / outage
-   for the gauss_markov and jakes presets at fixed seed) asserted
+   for every correlated preset at fixed seed) asserted
    bit-identical between the host round loop and the one-dispatch
    ``run_rounds`` scan, whose in-scan channel tap must replay the host
    simulator.
@@ -43,7 +43,7 @@ from repro.core.scenario import (
 )
 
 _GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "data", "scenario_golden.json")
-_GOLDEN_PRESETS = ("gauss_markov", "jakes")
+_GOLDEN_PRESETS = ("gauss_markov", "jakes", "gilbert_elliott", "mobility")
 _GOLDEN_SELS = [[0, 1], [2, 3], [1, 2]]
 _GOLDEN_CHAN = ChannelConfig(
     bandwidth_hz=2e5, mean_snr_db=2.0, min_k=0, dropout_prob=0.25
@@ -366,6 +366,13 @@ def test_golden_trajectory_host_vs_scan(preset):
                 assert st.snr_db == pytest.approx(g, abs=5e-3)
 
 
+def write_golden(path: str, record: dict) -> None:
+    """One line a preset, so a regenerated record diffs by preset."""
+    lines = [f" {json.dumps(name)}: {json.dumps(rec)}" for name, rec in record.items()]
+    with open(path, "w") as f:
+        f.write("{\n" + ",\n".join(lines) + "\n}\n")
+
+
 def test_golden_record_is_current():
     """The committed record covers exactly the golden presets (catches a
     stale file after an intentional regeneration)."""
@@ -385,9 +392,7 @@ if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(__file__))
     if "--regen" in sys.argv:
         os.makedirs(os.path.dirname(_GOLDEN_PATH), exist_ok=True)
-        record = {p: _golden_record(p) for p in _GOLDEN_PRESETS}
-        with open(_GOLDEN_PATH, "w") as f:
-            json.dump(record, f, indent=1)
+        write_golden(_GOLDEN_PATH, {p: _golden_record(p) for p in _GOLDEN_PRESETS})
         print(f"wrote {_GOLDEN_PATH}")
     else:
         print("usage: PYTHONPATH=src python tests/test_scenario.py --regen")

@@ -390,22 +390,6 @@ def test_fed_ckpt_directly_servable(tmp_path):
     )
 
 
-# ---------------------------------------------------------------------------
-# api_redesign shims
-# ---------------------------------------------------------------------------
-
-
-def test_launch_steps_shims():
-    from repro.launch.steps import make_prefill_step, make_serve_step
-    from repro.serve.steps import (
-        make_decode_step,
-        make_prefill_step as serve_prefill,
-    )
-
-    assert make_serve_step is make_decode_step
-    assert make_prefill_step is serve_prefill
-
-
 def test_serve_config_frozen_and_hashable():
     scfg = ServeConfig(model=DENSE, batch=2)
     hash(scfg)

@@ -7,10 +7,11 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.configs.gpt2_paper import REDUCED_CLIENT
-from repro.launch.steps import chunked_lm_loss, make_serve_step, make_train_step
+from repro.launch.steps import chunked_lm_loss, make_train_step
 from repro.models import backbone, init
 from repro.models.model import _lm_logits
 from repro.optim import adamw_init
+from repro.serve import make_decode_step
 
 pytestmark = pytest.mark.slow  # model-zoo/layer suites ride the slow tier
 
@@ -71,7 +72,7 @@ def test_serve_step_updates_length():
     from repro.models import init_cache
 
     cache = init_cache(cfg, 2, 16)
-    step = jax.jit(make_serve_step(cfg))
+    step = jax.jit(make_decode_step(cfg))
     logits, cache = step(params, cache, jnp.array([1, 2]))
     assert logits.shape == (2, cfg.vocab_size)
     assert int(cache["length"]) == 1

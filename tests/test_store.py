@@ -24,7 +24,7 @@ from repro.core.channel import BatchedChannelState, ChannelState
 from repro.data import make_banking77_like
 from repro.fed import FedConfig, run_federated
 from repro.fed.client import Client
-from repro.fed.engine import BatchedEngine, FusedE2EEngine, make_engine
+from repro.fed.engines import BatchedEngine, FusedE2EEngine, make_engine
 from repro.fed.server import Server
 from repro.fed.store import DeviceFleetStore, HostFleetStore, make_fleet_store
 
@@ -259,6 +259,16 @@ def test_from_template_lazy_rows():
     big = mk(100_000)
     assert big.device_bytes() == st.device_bytes()
     assert big.num_clients == 100_000
+
+
+def test_host_store_device_bytes_below_device_store():
+    """The fleet leaves the device: between rounds the host store keeps only
+    the shared backbone there, strictly less than the device store's
+    stacked fleet at N=10."""
+    loras, frozens, opts = _toy(10)
+    kw = dict(loras=loras, frozens=frozens, opts=opts, shared=True)
+    host = make_fleet_store("host", **kw).device_bytes()
+    assert 0 < host < make_fleet_store("device", **kw).device_bytes()
 
 
 def test_make_fleet_store_spec():
